@@ -340,7 +340,9 @@ def main() -> None:
         devices = max(int(p) for p in counts)
 
     # must land before jax initializes — this module delays every
-    # jax-importing import into run() for exactly this reason
+    # jax-importing import into run() for exactly this reason.  The flag
+    # only adds host (CPU) devices: on a TPU host jax.devices() is still
+    # the chips
     if "jax" not in sys.modules:
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
@@ -350,13 +352,20 @@ def main() -> None:
 
     if base_w is not None:
         run_at_workload(base_w, out_json=args.out)
-    elif args.smoke:
-        run(n_keys=args.n_keys or 16_384, n_reads=2_048, n_ops=2_048,
-            n_warmup=4_096, batch_size=1_024, repeats=2, delta_cap=256,
-            out_json=args.out)
     else:
-        run(**{**({"n_keys": args.n_keys} if args.n_keys else {}),
-               **({"out_json": args.out} if args.out else {})})
+        run_profile(args.smoke, args.n_keys, args.out)
+
+
+def run_profile(smoke: bool, n_keys: int | None = None,
+                out_json: str | None = None):
+    """The ``--smoke`` or default profile (``benchmarks.run --only
+    sharded`` calls this in its own process on a TPU host)."""
+    if smoke:
+        return run(n_keys=n_keys or 16_384, n_reads=2_048, n_ops=2_048,
+                   n_warmup=4_096, batch_size=1_024, repeats=2,
+                   delta_cap=256, out_json=out_json)
+    return run(**{**({"n_keys": n_keys} if n_keys else {}),
+                  **({"out_json": out_json} if out_json else {})})
 
 
 if __name__ == "__main__":

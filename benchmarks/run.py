@@ -107,9 +107,17 @@ def _compare_rerun(name: str, base: dict, path: str):
 
         return bench_streamed.run_at_workload(w, out_json=None)
     if name.startswith("BENCH_sharded"):
-        # the sharded bench needs the baseline's forced device topology,
-        # and XLA_FLAGS must land before jax initializes — jax is already
-        # up in this process, so rerun in a subprocess and read its JSON
+        import jax
+
+        if jax.default_backend() != "cpu":
+            # a chip belongs to one process: run on this process's
+            # devices, never in a child that would contend for them
+            from benchmarks import bench_sharded
+
+            return bench_sharded.run_at_workload(w, out_json=None)
+        # the CPU rerun needs the baseline's forced device topology, and
+        # XLA_FLAGS must land before jax initializes — jax is already up
+        # in this process, so rerun in a subprocess and read its JSON
         import subprocess
         import tempfile
 
@@ -195,6 +203,11 @@ def main() -> None:
                          "JSON and exit nonzero on >15%% throughput "
                          "regression or any wrong > 0 (repeatable)")
     args = ap.parse_args()
+    import jax
+
+    from repro.kernels.backend import enable_compile_cache
+
+    enable_compile_cache()
     if args.compare:
         sys.exit(1 if compare(args.compare) else 0)
     only = (set(t for part in args.only for t in part.split(","))
@@ -342,11 +355,17 @@ def main() -> None:
         else:
             rows += bench_streamed.rows(bench_streamed.run(
                 n_keys=max(n_keys, 131_072) if args.full else 131_072))
-    if want("sharded"):
-        # §13 sharded serving at P=1 vs P=4: needs a forced multi-device
-        # host, and XLA_FLAGS must land before jax initializes — jax is
-        # already up in this process, so the bench runs as a subprocess
-        # (it prints its own rows and emits BENCH_sharded.json)
+    if want("sharded") and jax.default_backend() != "cpu":
+        # §13 sharded serving at P=1 vs P=4 on this host's chips, in
+        # this process: a chip belongs to one process, and this one
+        # already holds them (it prints its rows, emits BENCH_sharded.json)
+        from benchmarks import bench_sharded
+
+        bench_sharded.run_profile(args.smoke, args.n_keys)
+    elif want("sharded"):
+        # on the CPU the bench needs a forced multi-device host, and
+        # XLA_FLAGS must land before jax initializes — jax is already up
+        # in this process, so the bench runs as a subprocess
         import subprocess
 
         env = dict(os.environ)

@@ -17,6 +17,7 @@ import numpy as np
 from repro.core.nfl import NFL, NFLConfig
 from repro.data.datasets import dataset_names, make_dataset
 from repro.data.workloads import MIXES, WorkloadConfig, make_workload
+from repro.kernels.backend import enable_compile_cache
 
 
 def _serve_mix(nfl, wl, *, ranges: bool, n_scans: int = 8):
@@ -56,6 +57,7 @@ def main():
     ap.add_argument("--n-ops", type=int, default=100_000)
     ap.add_argument("--batch-size", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
 
     keys = make_dataset(args.dataset, args.n_keys)
     flat = args.backend == "flat"

@@ -8,9 +8,11 @@ import numpy as np
 from repro.core.nfl import NFL, NFLConfig
 from repro.data.datasets import make_dataset
 from repro.index import make_index
+from repro.kernels.backend import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. a hard key distribution (the paper's longlat composite keys)
     keys = make_dataset("longlat", 100_000)
     payloads = np.arange(len(keys), dtype=np.int64)

@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.kernels.backend import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve.kv_cache import PagedKVCache, PagedKVConfig
 from repro.serve.prefix_cache import composite_key
@@ -18,6 +19,7 @@ from repro.serve.scheduler import ContinuousBatcher, Request, ServeConfig
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen3-14b", smoke=True)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -14,6 +14,7 @@ import dataclasses
 from repro.configs import get_config
 from repro.configs.base import AttnConfig
 from repro.data.tokens import SyntheticTokens
+from repro.kernels.backend import enable_compile_cache
 from repro.models.model import build_model
 from repro.train.optimizer import AdamWConfig
 from repro.train.schedule import ScheduleConfig
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--resume", action="store_true",
                     help="keep an existing checkpoint dir (default: fresh)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if not args.resume:
         import shutil

@@ -105,7 +105,7 @@ def f64_upcast_jaxpr():
     def serve(pk):
         # x64 is disabled repo-wide, so model the upcast the way it
         # actually bites: an f64 constant table captured into the trace.
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             table = jnp.linspace(0.0, 1.0, 8, dtype=jnp.float64)
         return jnp.searchsorted(table.astype(jnp.float32), pk)
     return _trace(serve, ((64,), jnp.float32))

@@ -68,8 +68,12 @@ def eqn_location(eqn) -> str:
         summary = source_info_util.summarize(eqn.source_info)
     except Exception:
         return "<unknown>"
-    # summarize() yields "path/to/file.py:123 (fn_name)"
-    return summary.split(" ")[0] if summary else "<unknown>"
+    # summarize() yields "path/to/file.py:123:7 (fn_name)"; findings key
+    # on file:line, so the column is dropped
+    if not summary:
+        return "<unknown>"
+    path, line = summary.split(" ")[0].split(":")[:2]
+    return f"{path}:{line}"
 
 
 def walk_jaxpr(jaxpr: jax_core.Jaxpr,

@@ -43,13 +43,14 @@ import contextlib
 import dataclasses
 from typing import Any, Callable, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.conflict import accept_candidate, dataset_tail_conflict
 
 __all__ = ["DriftConfig", "DriftMonitor", "ExclusionLock",
            "LockDisciplineError", "ReflowManager", "ReshardConfig",
-           "ReshardManager"]
+           "ReshardManager", "DEVICE_ERRORS", "MUST_PROPAGATE"]
 
 
 class LockDisciplineError(RuntimeError):
@@ -64,9 +65,17 @@ class LockDisciplineError(RuntimeError):
     corrupt the episode bookkeeping silently.  This error makes the
     violation loud.  It is a programming error, never a data-dependent
     failure, so the state machine's ``except Exception`` degradation
-    ladder deliberately re-raises it instead of counting it as a failed
-    retrain episode.
+    ladder deliberately re-raises it (with ``DEVICE_ERRORS``) instead of
+    counting it as a failed retrain episode.
     """
+
+
+# A compile or runtime failure of the device (an XLA or Mosaic error, or
+# a primitive with no lowering) inside a re-flow or reshard step is not
+# a failed episode: counting it as one would keep serving on a broken
+# build and exit clean.  These propagate, like a discipline violation.
+DEVICE_ERRORS = (jax.errors.JaxRuntimeError, NotImplementedError)
+MUST_PROPAGATE = (LockDisciplineError,) + DEVICE_ERRORS
 
 
 class ExclusionLock:
@@ -354,7 +363,7 @@ class ReflowManager:
         self.checks += 1
         try:
             tail = int(self.serving_tail(sample))
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             return  # measurement failure is never a serving-path error
@@ -371,7 +380,7 @@ class ReflowManager:
         self.retrain_attempts += 1
         try:
             trainer = self.train_factory(sample, self._episode_attempts)
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             self._fail()
@@ -387,7 +396,7 @@ class ReflowManager:
                 if self._trainer.step():
                     self._validate()
                     return
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             self._fail()
@@ -400,7 +409,7 @@ class ReflowManager:
         try:
             cand_tail, candidate = self.evaluate(self._trainer, sample)
             cand_tail = int(cand_tail)
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             self._fail()
@@ -431,7 +440,7 @@ class ReflowManager:
         epoch = self.reflows_completed
         try:
             started = bool(self.apply(best, use_flow, best_tail))
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             self._fail()
@@ -639,7 +648,7 @@ class ReshardManager:
             reads = np.asarray(snap["reads"], np.float64)
             writes = np.asarray(snap["writes"], np.float64)
             n_keys = int(np.sum(snap["n_keys"]))
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             return  # measurement failure is never a serving-path error
@@ -674,7 +683,7 @@ class ReshardManager:
         epoch = self.migrations_completed + self.migrations_failed
         try:
             started = bool(self.start_migration(lo, hi))
-        except LockDisciplineError:
+        except MUST_PROPAGATE:
             raise
         except Exception:
             self._fail()

@@ -46,7 +46,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.conflict import fit_linear_model, tail_conflict_degree
-from repro.kernels.fused_lookup import TOMBSTONE, _pow2ceil
+from repro.kernels.fused_lookup import (
+    TOMBSTONE,
+    _pow2ceil,
+    merge_tiers,
+    positioning_keys,
+)
 
 __all__ = ["FlatAFLI", "FlatAFLIConfig", "FlatArrays", "TOMBSTONE"]
 
@@ -712,7 +717,9 @@ def flat_lookup(arrays: FlatArrays, qkey: jnp.ndarray, qhi: jnp.ndarray,
                 qlo: jnp.ndarray, max_depth: int, dense_iters: int,
                 bucket_cap: int, dense_window: int = 8) -> jnp.ndarray:
     """Batched traversal over the flattened pools, pure jnp (DESIGN.md
-    §3).  Returns payload (i32) or -1.
+    §3).  Returns payload (i32) or -1.  ``arrays`` is a ``FlatArrays``
+    or its bucketed ``KernelPools`` twin (same fields, padding never
+    addressed).
 
     This is the executable specification for the fused kernel's
     traversal stage (§9): ``kernels/fused_lookup`` must stay
@@ -773,8 +780,7 @@ def flat_lookup(arrays: FlatArrays, qkey: jnp.ndarray, qhi: jnp.ndarray,
 
         # bucket scan (vectorized over the fixed capacity)
         bid = jnp.maximum(arrays.echild[e], 0)
-        brow_k = arrays.bkey[bid]          # [nq, cap]
-        brow_hi = arrays.bhi[bid]
+        brow_hi = arrays.bhi[bid]          # [nq, cap]
         brow_lo = arrays.blo[bid]
         brow_pv = arrays.bpayload[bid]
         match = (brow_hi == qhi[:, None]) & (brow_lo == qlo[:, None]) & (
@@ -803,6 +809,38 @@ def flat_lookup(arrays: FlatArrays, qkey: jnp.ndarray, qhi: jnp.ndarray,
     done0 = jnp.zeros((nq,), bool)
     _, result, _, _ = jax.lax.while_loop(cond, body, (node0, result0, done0, 0))
     return result
+
+
+@partial(jax.jit, static_argnames=(
+    "dim", "shapes", "max_depth", "dense_iters", "bucket_cap",
+    "dense_window", "use_flow", "interpret", "probe_tiers", "run_iters",
+    "run_window", "delta_iters", "delta_window"))
+def xla_lookup(pools, feats: jnp.ndarray, qhi: jnp.ndarray,
+               qlo: jnp.ndarray, packed_w: jnp.ndarray, tiers=None, *,
+               dim: int, shapes=(), max_depth: int, dense_iters: int,
+               bucket_cap: int, dense_window: int = 8,
+               use_flow: bool = True, interpret: bool = False,
+               probe_tiers: bool = False, run_iters: int = 1,
+               run_window: int = 4, delta_iters: int = 1,
+               delta_window: int = 4):
+    """The point route of a compiled TPU backend (DESIGN.md §2): the
+    NF forward (``nf_forward_pallas``, compiled by Mosaic), the
+    ``flat_lookup`` traversal and the write-tier merge
+    (``fused_lookup.merge_tiers``) as ONE jitted dispatch over the
+    device-resident bucketed pools.  Arguments as
+    ``fused_lookup_pallas`` (``interpret`` applies to the NF kernel
+    alone); returns ``(payload i32[B] or -1, positioning key f32[B])``,
+    bit-identical to the fused kernel, whose traversal mirrors
+    ``flat_lookup`` op for op."""
+    z = positioning_keys(feats, packed_w, shapes, dim, use_flow, interpret)
+    res = flat_lookup(pools, z, qhi, qlo, max_depth=max_depth,
+                      dense_iters=dense_iters, bucket_cap=bucket_cap,
+                      dense_window=dense_window)
+    if probe_tiers and tiers is not None:
+        res = merge_tiers(res, z, qhi, qlo, tiers, run_iters=run_iters,
+                          run_window=run_window, delta_iters=delta_iters,
+                          delta_window=delta_window)
+    return res, z
 
 
 class FlatAFLI:

@@ -52,6 +52,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 
+from repro.core.drift import DEVICE_ERRORS
 from repro.core.flat_afli import (
     TOMBSTONE,
     FlatAFLI,
@@ -60,7 +61,7 @@ from repro.core.flat_afli import (
     _ids64,
     split_key_bits,
 )
-from repro.dist.sharding import named_sharding, shard_mesh
+from repro.dist.sharding import shard_mesh
 from repro.kernels.shard_dispatch import (
     choose_boundaries,
     fanout_plan,
@@ -468,7 +469,7 @@ class ShardedFlatAFLI:
         self.shards: List[FlatAFLI] = [FlatAFLI(self.cfg)
                                        for _ in range(self.n_shards)]
         self.boundaries = np.empty(0, np.float32)   # f32[P-1], host copy
-        self._boundaries_dev = None                 # replicated device copy
+        self._boundaries_dev = None                 # router-device copy
         self._serve_flow = None
         self._reflow: Optional[_ShardedReflow] = None   # §14 coordinator
         self.n_reflows = 0
@@ -509,13 +510,12 @@ class ShardedFlatAFLI:
         if self.boundaries.shape[0] == 0:
             self._boundaries_dev = None
             return
-        b = jnp.asarray(self.boundaries)
-        if self.mesh is not None:
-            # tiny (P-1 floats) but serve-critical: replicate explicitly
-            # across the shard mesh so the router never waits on a
-            # cross-device fetch — the dist package's one-liner for it
-            b = jax.device_put(b, named_sharding(self.mesh))
-        self._boundaries_dev = b
+        # committed to the router's device (shard 0's): the router is a
+        # one-device program, and a copy replicated across the shard
+        # mesh would make it a P-device SPMD program that the NF
+        # kernel cannot be partitioned into
+        self._boundaries_dev = jax.device_put(jnp.asarray(self.boundaries),
+                                              self.devices[0])
 
     def _route_points(self, z32: np.ndarray) -> np.ndarray:
         return route(z32, self.boundaries)
@@ -620,6 +620,8 @@ class ShardedFlatAFLI:
         r = self._reshard
         try:
             done = r.tick(budget)
+        except DEVICE_ERRORS:
+            raise
         except Exception:
             self._reshard = None
             for s in range(r.lo, r.hi + 1):

@@ -9,11 +9,17 @@ the *same call site* runs compiled on hardware and interpreted in CI
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional
 
 import jax
 
-__all__ = ["should_interpret", "resolve_interpret", "pow2_batch"]
+__all__ = ["should_interpret", "resolve_interpret", "pow2_batch",
+           "enable_compile_cache"]
+
+# the checkout root: src/repro/kernels/backend.py -> three levels up
+_REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
 def pow2_batch(n: int, floor: int = 64) -> int:
@@ -35,3 +41,22 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return should_interpret()
     return bool(interpret)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (``chip_smoke.py``, ``benchmarks/run.py``, ``launch/serve.py``,
+    ``examples/*``); the tier-1 tests never call it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no other directory is set here.  Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` — the path is part of the cache key,
+    so it never depends on a temporary name, a pid or the time.  Every
+    program is cached, however fast it compiled.  Returns the directory
+    in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

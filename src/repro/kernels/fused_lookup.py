@@ -1,44 +1,36 @@
-"""Pallas TPU kernel: fused single-dispatch lookup (DESIGN.md §9).
+"""Pallas kernel: fused single-dispatch lookup (DESIGN.md §9).
 
-The serving hot path used to be two device dispatches with a host round
-trip between them: ``nf_forward_pallas`` (NF transform) then the pure-jnp
-``flat_lookup`` while-loop (multi-level FlatAFLI traversal, one full-batch
-HBM gather round per tree level).  Learned-index throughput lives and dies
-on exactly these per-lookup constant factors (Kraska et al.; Marcus et
-al.), so this kernel folds the whole read path into ONE ``pallas_call``:
+One ``pallas_call`` per query batch folds the read path together:
 
-1. **NF forward** — the unrolled Numerical-NF inference over the [TILE]
-   lane batch, via the same ``apply_flow_tile`` helper ``nf_forward_pallas``
-   compiles, so build-time and serve-time positioning keys are
-   bit-identical;
-2. **multi-level traversal** — an in-kernel *unrolled* loop over
-   ``max_depth`` (tree heights after the NF transform are 2-3, paper
-   Table 1) with per-query active masks.  Each level runs all three node
-   resolutions — model-node FMA slot prediction, dense-node
-   fixed-iteration binary search, conflict-bucket scan — and selects per
-   query, exactly mirroring the ``flat_lookup`` oracle so results are
-   bit-identical;
+1. **positioning keys** — with the flow on, the wrapper computes z with
+   ``nf_forward_pallas``, the kernel that positioned the build, inside
+   the same jit: each key's NF value is computed once, by one kernel, so
+   build-time and serve-time positioning keys are bit-identical;
+2. **multi-level traversal** — a bounded level loop (tree heights after
+   the NF transform are 2-3, paper Table 1) with per-query active masks.
+   Each level runs all three node resolutions — model-node FMA slot
+   prediction, dense-node fixed-iteration binary search, conflict-bucket
+   scan — and selects per query, exactly mirroring the ``flat_lookup``
+   oracle so results are bit-identical;
 3. **exact identity resolution** — 64-bit (hi, lo) key identity compares,
    emitting payloads in one VMEM round trip;
 4. **in-kernel write-path tiers** — the compacted run and active delta
    (log-structured inserts, DESIGN.md §10) ride along as sorted VMEM
-   pools probed by bounded binary search + newest-match window scan, so
-   mixed read/insert batches stay a single dispatch with no host-side
-   delta probe.
+   pools probed by bounded binary search + newest-match window scan
+   (``merge_tiers``), so mixed read/insert batches stay a single dispatch
+   with no host-side delta probe.
 
-The flattened node/entry/bucket pools (``FlatArrays.to_kernel_args``) ride
-along as grid-invariant VMEM blocks: after the NF transform the pools are
-small enough for VMEM residency on real workloads; the
-``kernels/ops.fused_lookup`` shim falls back to the two-dispatch oracle
-path when they are not.
+The traversal indexes the pools with data-dependent vector gathers,
+which Mosaic does not lower ("Only 2D gather is supported"), so this
+kernel runs in interpret mode only; on a compiled TPU backend
+``kernels/ops.fused_lookup`` serves the same traversal as XLA
+(``flat_afli.xla_lookup``, DESIGN.md §2).
 
 Grid: (ceil(B / TILE),) — a real tiled grid over the query batch with
-the pools as grid-invariant blocks (DESIGN.md §11).  TILE is
-lane-aligned on TPU; the interpret tile is a multi-step-grid throughput
-choice (``select_tile``).  Per-level work is batch-gated: the dense
-binary search + duplicate scan run only on levels where some live query
-sits on a dense node, and each write tier's probe only while the tier
-is non-empty.
+the pools as grid-invariant blocks (DESIGN.md §11); ``select_tile``
+picks TILE.  Per-level work is batch-gated: the dense binary search +
+duplicate scan run only on levels where some live query sits on a dense
+node, and each write tier's probe only while the tier is non-empty.
 """
 
 from __future__ import annotations
@@ -51,15 +43,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.backend import resolve_interpret
-from repro.kernels.nf_forward import DEFAULT_TILE as NF_TILE
-from repro.kernels.nf_forward import apply_flow_tile
+from repro.kernels.nf_forward import nf_forward_pallas
 
 __all__ = ["fused_lookup_pallas", "KernelPools", "TierPools", "TierPack",
-           "DEFAULT_TILE", "INTERPRET_TILE", "NF_TILE", "TOMBSTONE",
-           "nf_forward_lanes", "lower_bound", "probe_pool",
-           "probe_pool_index"]
+           "DEFAULT_TILE", "INTERPRET_TILE", "TOMBSTONE", "lower_bound",
+           "probe_pool", "probe_pool_index", "merge_tiers",
+           "positioning_keys"]
 
-DEFAULT_TILE = 512       # lane-aligned query tile for compiled TPU runs
+DEFAULT_TILE = 512       # lane-aligned query tile for a compiled grid
 INTERPRET_TILE = 2048    # CPU validation: per-step query tile of the
 #                          tiled grid (a 4k+ batch is a multi-step grid,
 #                          not one giant block — DESIGN.md §11)
@@ -75,29 +66,25 @@ TOMBSTONE = -2
 
 
 # ---------------------------------------------------------------- shared
-# traversal helpers, used by this kernel AND kernels/range_scan.py AND
-# kernels/streamed_lookup.py (the fused range-scan and HBM-streaming
-# paths reuse the same tiled-grid machinery: NF sub-tile discipline,
-# bounded lower-bound search, identity-window probes).
+# traversal helpers, used by this kernel, kernels/range_scan.py,
+# kernels/streamed_lookup.py AND the XLA routes (bounded lower-bound
+# search, identity-window probes, write-tier precedence).  They take
+# arrays, not refs, so the same code runs inside a kernel body and as
+# plain XLA.
 
-def nf_forward_lanes(feat_ref, w_ref, dim: int, shapes) -> jnp.ndarray:
-    """NF forward over one [tile] lane batch of expanded features.
+def positioning_keys(feats, packed_w, shapes, dim: int, use_flow: bool,
+                     interpret: bool) -> jnp.ndarray:
+    """Query features -> f32 positioning keys, once per batch.
 
-    Evaluated in fixed NF_TILE-wide sub-tiles no matter the query tile:
-    XLA elementwise codegen (tanh) is 1-ulp shape-dependent, and precise
-    placement needs serve-time keys bit-equal to the build transform's
-    (which runs the same [NF_TILE] blocks in nf_forward_pallas).  The
-    optimization barrier fences each sub-tile from downstream consumers —
-    without it XLA horizontally re-fuses the sub-chains into one wide
-    (shape-divergent) loop.  Callers must still pin ONE evaluation by
-    round-tripping the result through an output ref (see _kernel)."""
-    tile_b = feat_ref.shape[0]
-    parts = []
-    for s in range(0, tile_b, NF_TILE):
-        cols = [feat_ref[s:s + NF_TILE, k] for k in range(dim)]
-        parts.append(jax.lax.optimization_barrier(
-            apply_flow_tile(cols, w_ref, dim, shapes)))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    With the flow on this is ``nf_forward_pallas`` — the kernel, tile
+    and block shape that produced the build-time keys
+    (``ops.nf_transform_keys``) — so serve-time z is bit-equal to the
+    z every key was placed by; with the flow off the [B, 1] feature
+    column IS the key."""
+    if use_flow:
+        return nf_forward_pallas(feats.astype(jnp.float32), packed_w,
+                                 shapes, dim, interpret=interpret)
+    return feats[:, 0].astype(jnp.float32)
 
 
 def lower_bound(ppk, n_pool, qkey, iters: int) -> jnp.ndarray:
@@ -224,42 +211,81 @@ class TierPack(NamedTuple):
         return self.pools.nbytes()
 
 
-def _kernel(feat_ref, qhi_ref, qlo_ref, w_ref,
+def merge_tiers(result, qkey, qhi, qlo, tiers: TierPools, *,
+                run_iters: int, run_window: int, delta_iters: int,
+                delta_window: int) -> jnp.ndarray:
+    """Write-tier precedence over a static-structure result (DESIGN.md
+    §10/§12): probe the compacted run and the active delta, newest copy
+    first — active delta > compacted run > ``result``.
+
+    Each tier is a sorted pool: bounded binary search locates the
+    equal-key neighborhood, then a static window scan resolves by exact
+    (hi, lo) identity ONLY — the positioning key is the locator, never
+    the matcher, so a query key 1 ulp off a stored copy still lands
+    within the adjacent equal-key runs the symmetric window covers.
+    Tiers keep insertion order within an equal-pkey window (stable
+    sort), so the largest matching index is the last write.  An
+    identity MATCH in a newer tier always wins — a TOMBSTONE (-2) match
+    masks any older copy below, then surfaces as a miss.  Mirrors the
+    host ``FlatAFLI._probe_delta`` oracle; parity must stay exact."""
+    def tier_stage(phi, plo, ppv, ppk, plen, iters, window):
+        n_pool = plen[0]
+        nmax = ppk.shape[0]
+
+        # length-gated: a tier that is empty right now (e.g. the run
+        # between a fold swap and the first shadow) skips its whole
+        # search+scan; misses are the only possible outcome anyway
+        def live(_):
+            return probe_pool(phi, plo, ppv, n_pool,
+                              lower_bound(ppk, n_pool, qkey, iters),
+                              nmax, window, qhi, qlo)
+
+        def empty(_):
+            return jnp.full(qkey.shape, -1, jnp.int32)
+
+        return jax.lax.cond(n_pool > 0, live, empty, None)
+
+    t = tiers
+    run_pay = tier_stage(t.run_hi, t.run_lo, t.run_pv, t.run_pk, t.run_len,
+                         run_iters, run_window)
+    dl_pay = tier_stage(t.dl_hi, t.dl_lo, t.dl_pv, t.dl_pk, t.dl_len,
+                        delta_iters, delta_window)
+    result = jnp.where(dl_pay != -1, dl_pay,
+                       jnp.where(run_pay != -1, run_pay, result))
+    return jnp.where(result == TOMBSTONE, -1, result)
+
+
+def empty_tiers() -> TierPools:
+    """Tiny dummy write tiers for a call without live tiers: the probe
+    stage is compiled out by the static ``probe_tiers`` flag, so these
+    only fill the argument slots."""
+    lane = jnp.zeros((128,), jnp.int32)
+    return TierPools(
+        run_pk=jnp.full((128,), jnp.inf, jnp.float32),
+        run_hi=jnp.zeros((128,), jnp.uint32),
+        run_lo=jnp.zeros((128,), jnp.uint32),
+        run_pv=jnp.full((128,), -1, jnp.int32), run_len=lane,
+        dl_pk=jnp.full((128,), jnp.inf, jnp.float32),
+        dl_hi=jnp.zeros((128,), jnp.uint32),
+        dl_lo=jnp.zeros((128,), jnp.uint32),
+        dl_pv=jnp.full((128,), -1, jnp.int32), dl_len=lane,
+    )
+
+
+def _kernel(z_ref, qhi_ref, qlo_ref,
             nkind_ref, nslope_ref, nicept_ref, noff_ref, nsize_ref,
             etype_ref, ekey_ref, ehi_ref, elo_ref, epay_ref, echild_ref,
             bhi_ref, blo_ref, bpay_ref, blen_ref,
             rpk_ref, rhi_ref, rlo_ref, rpv_ref, rlen_ref,
             dpk_ref, dhi_ref, dlo_ref, dpv_ref, dlen_ref,
-            pay_ref, z_ref, *,
-            dim: int, shapes: Tuple[Tuple[int, int], ...], max_depth: int,
-            dense_iters: int, bucket_cap: int, dense_window: int,
-            use_flow: bool, probe_tiers: bool, run_iters: int,
+            pay_ref, *, max_depth: int, dense_iters: int, bucket_cap: int,
+            dense_window: int, probe_tiers: bool, run_iters: int,
             run_window: int, delta_iters: int, delta_window: int):
-    """One [TILE] query tile: NF forward + full traversal -> payloads.
+    """One [TILE] query tile: full traversal + tier probe -> payloads.
 
     Mirrors ``repro.core.flat_afli.flat_lookup`` op-for-op (the oracle);
     any change here must keep the parity tests bit-exact.
     """
-    # ---- (1) NF forward: feature columns -> positioning keys.
-    # Computed in fixed NF_TILE-wide sub-tiles no matter the query tile:
-    # XLA elementwise codegen (tanh) is 1-ulp shape-dependent, and precise
-    # placement needs serve-time keys bit-equal to the build transform's
-    # (which runs the same [NF_TILE] blocks in nf_forward_pallas).  The
-    # optimization barrier fences each sub-tile from the traversal
-    # consumers — without it XLA horizontally re-fuses the sub-chains into
-    # one wide (shape-divergent) loop.
-    if use_flow:
-        qkey = nf_forward_lanes(feat_ref, w_ref, dim, shapes)
-    else:
-        qkey = feat_ref[:, 0]
-    # materialize the positioning keys through the output ref: the VMEM
-    # round trip pins ONE evaluation of the NF chain.  Without it XLA
-    # re-materializes the tanh chain per consumer shape (1-ulp divergent
-    # even behind optimization_barrier), and the tier probe's exact
-    # f32-equality compares see keys that differ from the emitted z —
-    # with it, traversal, tier probe, and the z output are bit-identical
-    # by construction.
-    z_ref[...] = qkey
     qkey = z_ref[...]
     qhi = qhi_ref[...]
     qlo = qlo_ref[...]
@@ -402,49 +428,15 @@ def _kernel(feat_ref, qhi_ref, qlo_ref, w_ref,
 
     # ---- (4) write-path tiers (DESIGN.md §10): probe the compacted run
     # and the active delta in-kernel so a mixed read/insert batch never
-    # needs a host-side delta round trip.  Each tier is a sorted pool:
-    # bounded binary search locates the equal-key neighborhood, then a
-    # static window scan resolves by exact (hi, lo) identity ONLY — the
-    # positioning key is the locator, never the matcher.  That split is
-    # load-bearing: XLA re-materializes the NF tanh chain per consumer
-    # shape (1-ulp divergent even behind optimization_barrier), so an
-    # f32-equality compare against qkey is not codegen-stable, but a
-    # +/-1-ulp perturbed qkey still lands the search within the adjacent
-    # equal-key runs (no f32 value exists strictly between 1-ulp
-    # neighbors), and the symmetric window covers them.  The NEWEST
-    # matching copy wins — tiers keep insertion order within an
-    # equal-pkey window (stable sort), so the largest matching index is
-    # the last write — and the freshest tier takes precedence:
-    # active delta > compacted run > static tree.  Mirrors the host
-    # ``FlatAFLI._probe_delta`` oracle; parity must stay exact.
+    # needs a host-side delta round trip
     if probe_tiers:
-        def tier_stage(phi, plo, ppv, ppk, n_pool, iters, window, nmax):
-            # length-gated: a tier that is empty right now (e.g. the run
-            # between a fold swap and the first shadow) skips its whole
-            # search+scan; misses are the only possible outcome anyway
-            def live(_):
-                return probe_pool(phi, plo, ppv, n_pool,
-                                  lower_bound(ppk, n_pool, qkey, iters),
-                                  nmax, window, qhi, qlo)
-
-            def empty(_):
-                return jnp.full(qkey.shape, -1, jnp.int32)
-
-            return jax.lax.cond(n_pool > 0, live, empty, None)
-
-        run_pay = tier_stage(rhi_ref[...], rlo_ref[...], rpv_ref[...],
-                             rpk_ref[...], rlen_ref[...][0], run_iters,
-                             run_window, rpk_ref.shape[0])
-        dl_pay = tier_stage(dhi_ref[...], dlo_ref[...], dpv_ref[...],
-                            dpk_ref[...], dlen_ref[...][0], delta_iters,
-                            delta_window, dpk_ref.shape[0])
-        # an identity MATCH in a newer tier always wins — including a
-        # TOMBSTONE (-2) match, which must mask any older copy below
-        # rather than fall through to it; the final mapping surfaces
-        # tombstones as misses
-        result = jnp.where(dl_pay != -1, dl_pay,
-                           jnp.where(run_pay != -1, run_pay, result))
-        result = jnp.where(result == TOMBSTONE, -1, result)
+        result = merge_tiers(
+            result, qkey, qhi, qlo,
+            TierPools(rpk_ref[...], rhi_ref[...], rlo_ref[...], rpv_ref[...],
+                      rlen_ref[...], dpk_ref[...], dhi_ref[...],
+                      dlo_ref[...], dpv_ref[...], dlen_ref[...]),
+            run_iters=run_iters, run_window=run_window,
+            delta_iters=delta_iters, delta_window=delta_window)
 
     pay_ref[...] = result
 
@@ -453,26 +445,20 @@ def _pow2ceil(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-def select_tile(b: int, use_flow: bool, tile: Optional[int] = None,
+def select_tile(b: int, tile: Optional[int] = None,
                 interpret: Optional[bool] = None) -> int:
     """Query-tile selection for the tiled grid (DESIGN.md §11).
 
     The batch is served as a grid over query tiles with the pools as
-    grid-invariant blocks.  Flow tiles are pinned to whole ``NF_TILE``
-    multiples (build/serve key bit-equality, see module docstring); the
-    no-flow tile is a pure throughput choice: power-of-two bucketed so
-    per-batch-size recompiles stay bounded, capped at ``DEFAULT_TILE``
-    compiled / ``INTERPRET_TILE`` interpreted so a large batch becomes a
-    multi-step grid instead of one giant block.  Exposed so the dispatch
-    shim can bill the per-step query blocks against the VMEM budget with
-    the same tile the kernel will actually use."""
+    grid-invariant blocks.  The tile is a pure throughput choice (the
+    NF runs before the grid, see ``positioning_keys``): power-of-two
+    bucketed so per-batch-size recompiles stay bounded, capped at
+    ``DEFAULT_TILE`` compiled / ``INTERPRET_TILE`` interpreted so a
+    large batch becomes a multi-step grid instead of one giant block.
+    Exposed so the dispatch shim can bill the per-step query blocks
+    against the VMEM budget with the same tile the kernel will actually
+    use."""
     interpret = resolve_interpret(interpret)
-    if use_flow:
-        if tile is None:
-            tile = NF_TILE
-        # whole sub-tiles only: a ragged final sub-tile would evaluate
-        # the NF on a different shape and break key bit-equality
-        return ((max(tile, NF_TILE) + NF_TILE - 1) // NF_TILE) * NF_TILE
     if tile is None:
         tile = INTERPRET_TILE if interpret else DEFAULT_TILE
     # never pad a small batch up to a huge tile; stay lane-aligned on TPU
@@ -510,7 +496,7 @@ def fused_lookup_pallas(
     delta_iters: int = 1,
     delta_window: int = 4,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused NF-transform + FlatAFLI traversal in one ``pallas_call``.
+    """NF transform + fused FlatAFLI traversal, one jitted dispatch.
 
     feats: [B, d] f32 expanded query features (``use_flow=True``) or
     [B, 1] positioning keys (``use_flow=False``); qhi/qlo: [B] u32 exact
@@ -524,71 +510,43 @@ def fused_lookup_pallas(
     batch needs no host-side delta probe; otherwise the key output feeds
     the host ``_probe_delta`` fallback.  Bit-identical to
     ``nf_forward_pallas`` + ``flat_lookup`` (+ the host tier probe) by
-    construction.  ``interpret=None`` auto-detects the backend.
-
-    Tile discipline (DESIGN.md §9): the in-kernel NF always evaluates in
-    fixed [NF_TILE] sub-tiles.  XLA's tanh codegen is 1-ulp
-    shape-dependent, so serve-time NF output is bit-equal to the build-time
-    transform (``nf_transform_keys``, same block shape) only when the
-    evaluated shape matches — and precise placement rides on that equality.
-    The traversal itself uses only IEEE-exact ops
-    (mul/add/rint/compare/gather) and is shape-robust, so the query tile is
-    a pure throughput choice (rounded to an NF_TILE multiple under flow).
+    construction: z IS ``nf_forward_pallas`` (``positioning_keys``) and
+    the traversal uses only IEEE-exact ops (mul/add/rint/compare/gather),
+    so the query tile is a pure throughput choice.  ``interpret=None``
+    auto-detects the backend.
     """
     interpret = resolve_interpret(interpret)
     if tiers is None:
-        # no write tiers: ride tiny dummy blocks through the call (the
-        # probe stage is compiled out by the static flag)
         probe_tiers = False
-        lane = jnp.zeros((128,), jnp.int32)
-        tiers = TierPools(
-            run_pk=jnp.full((128,), jnp.inf, jnp.float32),
-            run_hi=jnp.zeros((128,), jnp.uint32),
-            run_lo=jnp.zeros((128,), jnp.uint32),
-            run_pv=jnp.full((128,), -1, jnp.int32), run_len=lane,
-            dl_pk=jnp.full((128,), jnp.inf, jnp.float32),
-            dl_hi=jnp.zeros((128,), jnp.uint32),
-            dl_lo=jnp.zeros((128,), jnp.uint32),
-            dl_pv=jnp.full((128,), -1, jnp.int32), dl_len=lane,
-        )
-    b = feats.shape[0]
-    # tiled grid over the query batch (pools ride as grid-invariant
-    # blocks).  Flow tiles are pinned: the NF must evaluate on the build
-    # transform's block shape for bit-equal serve-time keys (see
-    # docstring) — sub-tiling plus an optimization barrier narrows but
-    # does not close the gap, so only NF_TILE multiples are safe.
-    tile = select_tile(b, use_flow, tile, interpret)
+        tiers = empty_tiers()
+    z = positioning_keys(feats, packed_w, shapes, dim, use_flow, interpret)
+    b = z.shape[0]
+    tile = select_tile(b, tile, interpret)
     b_pad = ((b + tile - 1) // tile) * tile
+    zq = z
     if b_pad != b:
-        feats = jnp.pad(feats, ((0, b_pad - b), (0, 0)))
+        zq = jnp.pad(z, (0, b_pad - b))
         qhi = jnp.pad(qhi, (0, b_pad - b))
         qlo = jnp.pad(qlo, (0, b_pad - b))
 
     qspec = pl.BlockSpec((tile,), lambda i: (i,))
-    fspec = pl.BlockSpec((tile, feats.shape[1]), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, packed_w.shape[1]), lambda i: (0, 0))
 
     def pool_spec(a):
         return pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
 
-    pay, z = pl.pallas_call(
+    pay = pl.pallas_call(
         functools.partial(
-            _kernel, dim=dim, shapes=shapes, max_depth=max_depth,
+            _kernel, max_depth=max_depth,
             dense_iters=dense_iters, bucket_cap=bucket_cap,
-            dense_window=dense_window, use_flow=use_flow,
-            probe_tiers=probe_tiers, run_iters=run_iters,
-            run_window=run_window, delta_iters=delta_iters,
-            delta_window=delta_window,
+            dense_window=dense_window, probe_tiers=probe_tiers,
+            run_iters=run_iters, run_window=run_window,
+            delta_iters=delta_iters, delta_window=delta_window,
         ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad,), jnp.float32),
-        ),
+        out_shape=jax.ShapeDtypeStruct((b_pad,), jnp.int32),
         grid=(b_pad // tile,),
-        in_specs=[fspec, qspec, qspec, wspec]
+        in_specs=[qspec, qspec, qspec]
         + [pool_spec(a) for a in pools] + [pool_spec(a) for a in tiers],
-        out_specs=(qspec, qspec),
+        out_specs=qspec,
         interpret=interpret,
-    )(feats.astype(jnp.float32), qhi, qlo, packed_w.astype(jnp.float32),
-      *pools, *tiers)
-    return pay[:b], z[:b]
+    )(zq, qhi, qlo, *pools, *tiers)
+    return pay[:b], z

@@ -14,7 +14,17 @@ drive the VPU with the batch laid out along lanes:
 * weights travel as one flat [1, n_params] block replicated to every grid
   step (a few hundred bytes).
 
-Grid: (ceil(B / TILE),).  TILE is lane-aligned (multiple of 128).
+Grid: (ceil(B / TILE),).  TILE is a multiple of 1024: Mosaic tiles the
+1-D f32 output block as T(TILE) and XLA lays a 1-D f32 array out as
+T(1024), and the two must match ("XLA layout ({0:T(1024)}) does not
+match Mosaic layout ({0:T(512)})" is what a 512 tile gets on v5e).
+
+This is the one NF evaluation on the flat serve path: the build
+transform (``ops.nf_transform_keys``), the point and range routes
+(``fused_lookup.positioning_keys``) and the shard router all call it
+with the same tile, so a key's serve-time z is bit-equal to the z it
+was placed by on every backend (DESIGN.md §9).  It is also the one
+kernel of the serve path that Mosaic compiles for the TPU.
 """
 
 from __future__ import annotations
@@ -31,7 +41,7 @@ from repro.kernels.backend import resolve_interpret
 __all__ = ["nf_forward_pallas", "pack_flow_weights", "apply_flow_tile",
            "DEFAULT_TILE"]
 
-DEFAULT_TILE = 512
+DEFAULT_TILE = 1024
 
 
 def pack_flow_weights(
@@ -63,10 +73,8 @@ def apply_flow_tile(cols, w_ref, dim: int,
 
     ``cols`` is the list of ``dim`` [TILE] feature-column vectors; ``w_ref``
     the packed [1, n] weight block (``pack_flow_weights`` layout).  Returns
-    the [TILE] transformed keys.  This is THE flow arithmetic: both
-    ``nf_forward_pallas`` and the fused lookup kernel
-    (``kernels/fused_lookup``) call it, so build-time and serve-time
-    positioning keys are bit-identical (DESIGN.md §9).
+    the [TILE] transformed keys.  This is THE flow arithmetic of
+    ``nf_forward_pallas`` (DESIGN.md §9).
     """
     idx = 0
 

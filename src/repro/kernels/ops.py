@@ -1,8 +1,15 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public jit'd wrappers around the Pallas kernels, and the dispatch
+shims that pick each serving route.
 
 ``interpret`` mode is selected automatically: Pallas executes the kernel
 bodies in Python on CPU (the validation platform) and compiles to Mosaic on
-real TPU backends.
+real TPU backends.  Which route serves a request follows one rule
+(``traversal_route``, DESIGN.md §2): the traversal kernels gather from
+their pools with vector indices that Mosaic does not lower, so a compiled
+backend serves points and ranges as XLA over the same device pools, with
+the NF forward still a Mosaic kernel; the interpreter runs the Pallas
+ladder.  The route that ran is named in every dispatch's ``info`` and
+counted in ``fused_lookup_stats``.
 """
 
 from __future__ import annotations
@@ -29,6 +36,7 @@ __all__ = [
     "fused_lookup",
     "fused_range_scan",
     "fused_lookup_stats",
+    "traversal_route",
     "reset_fused_lookup_stats",
     "pool_nbytes",
     "kernel_block_bytes",
@@ -44,9 +52,10 @@ def nf_transform_keys(
     normalizer: KeyNormalizer,
     keys: np.ndarray,
     cfg: FlowConfig,
-    tile: int = 512,
 ) -> np.ndarray:
-    """Kernel-backed version of ``repro.core.flow.transform_keys``."""
+    """Kernel-backed version of ``repro.core.flow.transform_keys``: the
+    build-time positioning keys, from the same ``nf_forward_pallas``
+    kernel and tile every serve route evaluates (DESIGN.md §9)."""
     keys = np.asarray(keys, dtype=np.float64)
     feats = expand_features(keys, normalizer, cfg.dim, cfg.theta, dtype=np.float32)
     weights = materialize_weights(params, cfg)
@@ -55,15 +64,19 @@ def nf_transform_keys(
     feat_sd = params.get("feat_sd", jnp.ones((cfg.dim,), jnp.float32))
     packed, shapes = pack_flow_weights(weights, out_scale, feat_mu, feat_sd)
     z = nf_forward_pallas(
-        jnp.asarray(feats), packed, shapes, cfg.dim, tile=tile,
+        jnp.asarray(feats), packed, shapes, cfg.dim,
         interpret=should_interpret(),
     )
     return np.asarray(z, dtype=np.float64)
 
 
 # ---------------------------------------------------------------- fused
-# Conservative per-core VMEM share for the grid-invariant pool blocks on
-# real TPUs (16 MiB/core minus query tiles and double-buffering headroom).
+# Per-core VMEM share for the grid-invariant pool blocks of a compiled
+# traversal kernel.  No route consults it on a compiled backend today:
+# the traversal kernels run in the interpreter only (``traversal_route``)
+# and the one compiled kernel, ``nf_forward_pallas``, holds a few KiB per
+# grid step.  A Mosaic traversal must set it, and ``vmem_limit_bytes``,
+# from what the compiler accepts for the chip's ``device_kind``.
 DEFAULT_VMEM_BUDGET = 12 * 2 ** 20
 # The CPU validation platform has no VMEM; cap where the single-block
 # interpret kernel stops being profitable against the jitted oracle.
@@ -136,9 +149,12 @@ def overflow_reason(parts, budget: int) -> Dict:
 # instead of inferring compiles from tail latencies.
 _FUSED_STATS = {
     "dispatch_count": 0,   # fused_lookup shim calls
+    "interpret_count": 0,  # point + range calls run by the Pallas
+    #                        interpreter (a chip run must show 0)
     "fused_count": 0,      # single-dispatch kernel path taken
+    "xla_count": 0,        # XLA point route taken (compiled backend)
     "fallback_count": 0,   # oracle fallback taken (budget exceeded)
-    "tier_kernel_count": 0,  # calls that probed the tiers in-kernel
+    "tier_kernel_count": 0,  # calls that resolved the tiers on device
     "host_probe_count": 0,   # calls whose tiers fell to the host oracle
     "retrace_count": 0,    # calls that paid a fresh XLA trace
     # HBM-streaming rung (DESIGN.md §17)
@@ -149,6 +165,7 @@ _FUSED_STATS = {
     # range-scan path (DESIGN.md §12)
     "scan_dispatch_count": 0,  # fused_range_scan shim calls
     "scan_fused_count": 0,     # single-dispatch range kernel taken
+    "scan_xla_count": 0,       # XLA range route taken (compiled backend)
     "scan_fallback_count": 0,  # host-oracle fallback taken
     "scan_trunc_count": 0,     # queries whose candidate span > scan_cap
 }
@@ -309,17 +326,32 @@ def fault_stall(point: str) -> None:
         time.sleep(s)
 
 
+def traversal_route(interpret: bool) -> str:
+    """The route rule (DESIGN.md §2): ``"pallas"`` where the traversal
+    kernels run (the interpreter), ``"xla"`` on a compiled backend.
+
+    Mosaic refuses every traversal kernel of this package — they index
+    their pools with data-dependent vector gathers ("Only 2D gather is
+    supported") — so on the TPU the traversal, the tier probe and the
+    range merge run as jitted XLA over the same device-resident pools,
+    behind the Mosaic-compiled ``nf_forward_pallas``.  The rule is
+    static: no dispatch ever catches a compile error to try another
+    route."""
+    return "pallas" if interpret else "xla"
+
+
 def serving_cache_size() -> int:
     """Total jit-cache entries across the serving dispatch routes."""
-    from repro.core.flat_afli import flat_lookup
+    from repro.core.flat_afli import flat_lookup, xla_lookup
     from repro.kernels.fused_lookup import fused_lookup_pallas
-    from repro.kernels.range_scan import fused_range_scan_pallas
+    from repro.kernels.range_scan import (fused_range_scan_pallas,
+                                          xla_range_scan)
     from repro.kernels.streamed_lookup import streamed_lookup_pallas
 
     total = 0
     for fn in (fused_lookup_pallas, streamed_lookup_pallas,
-               fused_range_scan_pallas, flat_lookup,
-               nf_forward_pallas):
+               fused_range_scan_pallas, flat_lookup, xla_lookup,
+               xla_range_scan, nf_forward_pallas):
         try:
             total += fn._cache_size()
         except AttributeError:  # not a jit wrapper (e.g. monkeypatched)
@@ -327,13 +359,59 @@ def serving_cache_size() -> int:
     return total
 
 
+def _xla_point(pools, feats, qhi, qlo, flow, tiers, *, max_depth: int,
+               dense_iters: int, bucket_cap: int, dense_window: int,
+               interpret: bool, sync: bool, cache_before: int):
+    """The XLA point route (``traversal_route`` == ``"xla"``): NF kernel
+    + ``flat_lookup`` traversal + device tier probe in one jitted
+    dispatch over the resident bucketed pools (``flat_afli.xla_lookup``).
+    Every pool size takes it, so no VMEM budget applies; the tiers are
+    always resolved on device — never by the host probe."""
+    from repro.core.flat_afli import xla_lookup
+
+    pools = pools() if callable(pools) else pools
+    tiers = tiers() if callable(tiers) else tiers
+    have_tiers = tiers is not None
+    if flow is not None:
+        packed_w, shapes = flow
+    else:
+        packed_w, shapes = jnp.zeros((1, 1), jnp.float32), ()
+    pay, z = xla_lookup(
+        pools, feats, qhi, qlo, packed_w,
+        tiers.pools if have_tiers else None,
+        dim=int(feats.shape[1]), shapes=shapes, max_depth=max_depth,
+        dense_iters=dense_iters, bucket_cap=bucket_cap,
+        dense_window=dense_window, use_flow=flow is not None,
+        interpret=interpret, probe_tiers=have_tiers,
+        run_iters=tiers.run_iters if have_tiers else 1,
+        run_window=tiers.run_window if have_tiers else 4,
+        delta_iters=tiers.delta_iters if have_tiers else 1,
+        delta_window=tiers.delta_window if have_tiers else 4,
+    )
+    retraced = serving_cache_size() > cache_before
+    _bump(xla_count=1, retrace_count=int(retraced),
+          tier_kernel_count=int(have_tiers))
+    info = {"path": "xla", "n_dispatch": 1,
+            "pool_bytes": pool_nbytes(pools),
+            "tier_bytes": tiers.nbytes() if have_tiers else 0,
+            "retraced": retraced,
+            "tier_path": "device" if have_tiers else "none",
+            "host_probe": False, "fallback_reason": None}
+    if not sync:
+        return pay, z, info
+    return np.asarray(pay), np.asarray(z), info
+
+
 def fused_lookup(arrays, pools, feats, qhi, qlo, *, flow=None,
                  max_depth: int, dense_iters: int, bucket_cap: int,
                  dense_window: int = 8, tiers=None, stream=None,
                  vmem_budget=None, tile=None, interpret=None,
                  sync: bool = True):
-    """Dispatch shim for the point-lookup ladder: fused -> streamed ->
-    oracle (DESIGN.md §9/§17).
+    """Dispatch shim for the point-lookup routes (DESIGN.md §2/§9/§17).
+
+    On a compiled backend every batch takes the XLA route
+    (``_xla_point``; ``traversal_route``).  In interpret mode the
+    Pallas ladder runs: fused -> streamed -> oracle.
 
     When the packed pools fit the VMEM budget, the whole read path — NF
     forward + multi-level traversal + identity resolution — runs as ONE
@@ -381,17 +459,24 @@ def fused_lookup(arrays, pools, feats, qhi, qlo, *, flow=None,
 
     interpret = resolve_interpret(interpret)
     forced = _fault_gate("point")
-    _bump(dispatch_count=1)
+    _bump(dispatch_count=1, interpret_count=int(interpret))
     cache_before = serving_cache_size()
     if vmem_budget is None:
         vmem_budget = (DEFAULT_INTERPRET_BUDGET if interpret
                        else DEFAULT_VMEM_BUDGET)
     use_flow = flow is not None
     dim = int(feats.shape[1])
+    if (traversal_route(interpret) == "xla" and vmem_budget > 0
+            and not forced):
+        return _xla_point(pools, feats, qhi, qlo, flow, tiers,
+                          max_depth=max_depth, dense_iters=dense_iters,
+                          bucket_cap=bucket_cap, dense_window=dense_window,
+                          interpret=interpret, sync=sync,
+                          cache_before=cache_before)
     # the VMEM bill is checked against the shapes the kernel will
     # actually hold resident: bucketed padded pools + the query tile
     # blocks of the tile the grid will use — not the raw pool bytes
-    q_tile = select_tile(int(feats.shape[0]), use_flow, tile, interpret)
+    q_tile = select_tile(int(feats.shape[0]), tile, interpret)
     nbytes = None
     if vmem_budget > 0 and not forced:
         if callable(pools):
@@ -439,9 +524,6 @@ def fused_lookup(arrays, pools, feats, qhi, qlo, *, flow=None,
         # pipeline bubbles compiled, per-step dispatch interpreted — so
         # co-optimize the two tiles for minimum total grid steps under
         # the budget instead of inheriting the fused rung's query tile.
-        # Doubling the query tile is bit-equality-safe: the NF forward
-        # always evaluates in fixed NF_TILE sub-tiles no matter the
-        # query-tile width (fused_lookup module docstring).
         b_n = int(feats.shape[0])
         floor_parts = stream_resident_parts(cap, router_len, t_bytes,
                                             MIN_STREAM_TILE, q_tile, dim)
@@ -596,7 +678,11 @@ def fused_lookup(arrays, pools, feats, qhi, qlo, *, flow=None,
 def fused_range_scan(scan_pack, tiers, feats_lo, feats_hi, *, flow=None,
                      scan_cap: int, host_fallback, vmem_budget=None,
                      tile=None, interpret=None):
-    """Dispatch shim for the fused tier-merged range scan (DESIGN.md §12).
+    """Dispatch shim for the tier-merged range scan (DESIGN.md §2/§12).
+
+    On a compiled backend every batch takes the XLA range route
+    (``range_scan.xla_range_scan``: the kernel's own merge body as one
+    jitted XLA program; ``traversal_route``).  In interpret mode:
 
     When the scan pool AND the write tiers fit the VMEM budget, the whole
     range path — endpoint NF forward + lower-bound location + three-way
@@ -623,14 +709,14 @@ def fused_range_scan(scan_pack, tiers, feats_lo, feats_hi, *, flow=None,
 
     interpret = resolve_interpret(interpret)
     forced = _fault_gate("scan")
-    _bump(scan_dispatch_count=1)
+    _bump(scan_dispatch_count=1, interpret_count=int(interpret))
     cache_before = serving_cache_size()
     if vmem_budget is None:
         vmem_budget = (DEFAULT_INTERPRET_BUDGET if interpret
                        else DEFAULT_VMEM_BUDGET)
     use_flow = flow is not None
     dim = int(feats_lo.shape[1])
-    q_tile = select_tile(int(feats_lo.shape[0]), use_flow, tile, interpret)
+    q_tile = select_tile(int(feats_lo.shape[0]), tile, interpret)
 
     nbytes = None
     if vmem_budget > 0 and not forced:
@@ -645,6 +731,34 @@ def fused_range_scan(scan_pack, tiers, feats_lo, feats_hi, *, flow=None,
         packed_w, shapes = flow
     else:
         packed_w, shapes = jnp.zeros((1, 1), jnp.float32), ()
+
+    if nbytes is not None and traversal_route(interpret) == "xla":
+        # the XLA range route: same merge body as the kernel, over the
+        # device pools at any size — no budget, never the host oracle
+        from repro.kernels.range_scan import xla_range_scan
+
+        have_tiers = tiers is not None
+        pv, cnt, tot = xla_range_scan(
+            feats_lo, feats_hi, packed_w, scan_pack.pool,
+            tiers.pools if have_tiers else None,
+            dim=dim, shapes=shapes, scan_cap=scan_cap,
+            scan_iters=scan_pack.iters, use_flow=use_flow,
+            interpret=interpret, probe_tiers=have_tiers,
+            run_iters=tiers.run_iters if have_tiers else 1,
+            run_window=tiers.run_window if have_tiers else 4,
+            delta_iters=tiers.delta_iters if have_tiers else 1,
+            delta_window=tiers.delta_window if have_tiers else 4,
+        )
+        pv, cnt, tot = np.asarray(pv), np.asarray(cnt), np.asarray(tot)
+        retraced = serving_cache_size() > cache_before
+        n_trunc = int((tot > scan_cap).sum())
+        _bump(scan_xla_count=1, retrace_count=int(retraced),
+              scan_trunc_count=n_trunc)
+        info = {"path": "xla", "n_dispatch": 1,
+                "pool_bytes": scan_pack.nbytes() + tier_bytes,
+                "retraced": retraced, "truncated": n_trunc,
+                "tier_path": "device" if have_tiers else "none"}
+        return pv, cnt, tot, info
 
     if nbytes is not None and nbytes <= vmem_budget:
         from repro.kernels.range_scan import fused_range_scan_pallas

@@ -3,8 +3,8 @@
 A batch of ``[lo, hi)`` range queries is answered in ONE ``pallas_call``,
 end to end:
 
-1. **NF forward on both endpoints** — the same fixed-``NF_TILE`` sub-tile
-   discipline as the fused point kernel (``nf_forward_lanes``), so the
+1. **NF forward on both endpoints** — ``positioning_keys``, the build
+   transform's own ``nf_forward_pallas``, inside the same jit, so the
    endpoint positioning keys are bit-equal to the build transform's;
 2. **lower-bound location** — each endpoint is located in three sorted
    pools with the shared bounded binary search (``lower_bound``): the
@@ -36,6 +36,12 @@ Grid: (ceil(B / TILE),) — the same tiled-grid machinery as
 grid-invariant VMEM blocks, and all static bounds (pool iteration
 counts, probe windows, ``scan_cap``) come ratcheted from the
 ``ServingState`` so steady-state range traffic cannot retrace.
+
+Steps 2-3 index the pools with vector gathers, which Mosaic does not
+lower, so the ``pallas_call`` runs in interpret mode only.  On a
+compiled TPU backend ``xla_range_scan`` runs the SAME body
+(``scan_merge``) as one jitted XLA program over the same device pools
+(DESIGN.md §2) — one code path, two lowerings.
 """
 
 from __future__ import annotations
@@ -51,13 +57,15 @@ from repro.kernels.backend import resolve_interpret
 from repro.kernels.fused_lookup import (
     TOMBSTONE,
     TierPools,
+    empty_tiers,
     lower_bound,
-    nf_forward_lanes,
+    positioning_keys,
     probe_pool,
     select_tile,
 )
 
-__all__ = ["fused_range_scan_pallas", "ScanPool", "ScanPack"]
+__all__ = ["fused_range_scan_pallas", "xla_range_scan", "scan_merge",
+           "ScanPool", "ScanPack"]
 
 
 class ScanPool(NamedTuple):
@@ -86,53 +94,28 @@ class ScanPack(NamedTuple):
         return self.pool.nbytes()
 
 
-def _kernel(flo_ref, fhi_ref, w_ref,
-            spk_ref, shi_ref, slo_ref, spv_ref, slen_ref,
-            rpk_ref, rhi_ref, rlo_ref, rpv_ref, rlen_ref,
-            dpk_ref, dhi_ref, dlo_ref, dpv_ref, dlen_ref,
-            pv_ref, cnt_ref, tot_ref, zlo_ref, zhi_ref, *,
-            dim: int, shapes: Tuple[Tuple[int, int], ...], scan_cap: int,
-            scan_iters: int, use_flow: bool, probe_tiers: bool,
-            run_iters: int, run_window: int, delta_iters: int,
-            delta_window: int):
-    """One [TILE] tile of range queries -> [TILE, scan_cap] payloads.
+def scan_merge(zlo, zhi, spool: ScanPool, tiers: TierPools, *,
+               scan_cap: int, scan_iters: int, probe_tiers: bool,
+               run_iters: int, run_window: int, delta_iters: int,
+               delta_window: int):
+    """Range queries ``[zlo, zhi)`` -> ``(payloads i32[B, scan_cap],
+    counts i32[B], totals i32[B])`` over the scan pool merged with the
+    write tiers.  Pure array code: the kernel body runs it on one query
+    tile, ``xla_range_scan`` on the whole batch.
 
     Mirrors ``repro.core.flat_afli._range_scan_host`` candidate-for-
     candidate (the host oracle); any change here must keep the parity
     tests bit-exact.
     """
-    # ---- (1) endpoint NF forward, pinned to ONE evaluation each via the
-    # output-ref round trip (exactly the point kernel's z_ref discipline:
-    # XLA re-materializes the tanh chain per consumer shape, and the
-    # three lower-bound consumers must all see the emitted key)
-    if use_flow:
-        zlo_ref[...] = nf_forward_lanes(flo_ref, w_ref, dim, shapes)
-        zhi_ref[...] = nf_forward_lanes(fhi_ref, w_ref, dim, shapes)
-    else:
-        zlo_ref[...] = flo_ref[:, 0]
-        zhi_ref[...] = fhi_ref[:, 0]
-    zlo = zlo_ref[...]
-    zhi = zhi_ref[...]
-
-    # pools, VMEM-resident for the whole tile
-    spk = spk_ref[...]
-    shi = shi_ref[...]
-    slo = slo_ref[...]
-    spv = spv_ref[...]
-    s_len = slen_ref[...][0]
-    rpk = rpk_ref[...]
-    rhi = rhi_ref[...]
-    rlo = rlo_ref[...]
-    rpv = rpv_ref[...]
-    r_len = rlen_ref[...][0]
-    dpk = dpk_ref[...]
-    dhi = dhi_ref[...]
-    dlo = dlo_ref[...]
-    dpv = dpv_ref[...]
-    d_len = dlen_ref[...][0]
-    smax = spk_ref.shape[0]
-    rmax = rpk_ref.shape[0]
-    dmax = dpk_ref.shape[0]
+    spk, shi, slo, spv = spool.pk, spool.hi, spool.lo, spool.pv
+    s_len = spool.plen[0]
+    rpk, rhi, rlo, rpv = tiers.run_pk, tiers.run_hi, tiers.run_lo, tiers.run_pv
+    r_len = tiers.run_len[0]
+    dpk, dhi, dlo, dpv = tiers.dl_pk, tiers.dl_hi, tiers.dl_lo, tiers.dl_pv
+    d_len = tiers.dl_len[0]
+    smax = spk.shape[0]
+    rmax = rpk.shape[0]
+    dmax = dpk.shape[0]
 
     # ---- (2) lower-bound both endpoints in every pool: [a, b) holds
     # exactly the pool entries with pk in [zlo, zhi) (searchsorted-left
@@ -218,10 +201,27 @@ def _kernel(flo_ref, fhi_ref, w_ref,
     _, _, _, cnt, out = jax.lax.fori_loop(
         0, scan_cap, merge_step, (s0, r0, d0, zero, out0))
 
+    return out, cnt, total
+
+
+def _kernel(zlo_ref, zhi_ref,
+            spk_ref, shi_ref, slo_ref, spv_ref, slen_ref,
+            rpk_ref, rhi_ref, rlo_ref, rpv_ref, rlen_ref,
+            dpk_ref, dhi_ref, dlo_ref, dpv_ref, dlen_ref,
+            pv_ref, cnt_ref, tot_ref, **statics):
+    """One [TILE] tile of range queries -> [TILE, scan_cap] payloads
+    (``scan_merge`` over the VMEM-resident pools)."""
+    out, cnt, total = scan_merge(
+        zlo_ref[...], zhi_ref[...],
+        ScanPool(spk_ref[...], shi_ref[...], slo_ref[...], spv_ref[...],
+                 slen_ref[...]),
+        TierPools(rpk_ref[...], rhi_ref[...], rlo_ref[...], rpv_ref[...],
+                  rlen_ref[...], dpk_ref[...], dhi_ref[...], dlo_ref[...],
+                  dpv_ref[...], dlen_ref[...]),
+        **statics)
     pv_ref[...] = out
     cnt_ref[...] = cnt
     tot_ref[...] = total
-
 
 @functools.partial(
     jax.jit,
@@ -269,38 +269,30 @@ def fused_range_scan_pallas(
     interpret = resolve_interpret(interpret)
     if tiers is None:
         probe_tiers = False
-        lane = jnp.zeros((128,), jnp.int32)
-        tiers = TierPools(
-            run_pk=jnp.full((128,), jnp.inf, jnp.float32),
-            run_hi=jnp.zeros((128,), jnp.uint32),
-            run_lo=jnp.zeros((128,), jnp.uint32),
-            run_pv=jnp.full((128,), -1, jnp.int32), run_len=lane,
-            dl_pk=jnp.full((128,), jnp.inf, jnp.float32),
-            dl_hi=jnp.zeros((128,), jnp.uint32),
-            dl_lo=jnp.zeros((128,), jnp.uint32),
-            dl_pv=jnp.full((128,), -1, jnp.int32), dl_len=lane,
-        )
-    b = feats_lo.shape[0]
-    tile = select_tile(b, use_flow, tile, interpret)
+        tiers = empty_tiers()
+    zlo = positioning_keys(feats_lo, packed_w, shapes, dim, use_flow,
+                           interpret)
+    zhi = positioning_keys(feats_hi, packed_w, shapes, dim, use_flow,
+                           interpret)
+    b = zlo.shape[0]
+    tile = select_tile(b, tile, interpret)
     b_pad = ((b + tile - 1) // tile) * tile
+    zlo_q, zhi_q = zlo, zhi
     if b_pad != b:
-        # zero-padded lanes transform to identical endpoints -> empty
-        # ranges -> zero counts; never observed by the caller's slice
-        feats_lo = jnp.pad(feats_lo, ((0, b_pad - b), (0, 0)))
-        feats_hi = jnp.pad(feats_hi, ((0, b_pad - b), (0, 0)))
+        # zero-padded lanes have identical endpoints -> empty ranges ->
+        # zero counts; never observed by the caller's slice
+        zlo_q = jnp.pad(zlo, (0, b_pad - b))
+        zhi_q = jnp.pad(zhi, (0, b_pad - b))
 
     qspec = pl.BlockSpec((tile,), lambda i: (i,))
-    fspec = pl.BlockSpec((tile, feats_lo.shape[1]), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, packed_w.shape[1]), lambda i: (0, 0))
     ospec = pl.BlockSpec((tile, scan_cap), lambda i: (i, 0))
 
     def pool_spec(a):
         return pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
 
-    pv, cnt, tot, zlo, zhi = pl.pallas_call(
+    pv, cnt, tot = pl.pallas_call(
         functools.partial(
-            _kernel, dim=dim, shapes=shapes, scan_cap=scan_cap,
-            scan_iters=scan_iters, use_flow=use_flow,
+            _kernel, scan_cap=scan_cap, scan_iters=scan_iters,
             probe_tiers=probe_tiers, run_iters=run_iters,
             run_window=run_window, delta_iters=delta_iters,
             delta_window=delta_window,
@@ -309,14 +301,56 @@ def fused_range_scan_pallas(
             jax.ShapeDtypeStruct((b_pad, scan_cap), jnp.int32),
             jax.ShapeDtypeStruct((b_pad,), jnp.int32),
             jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((b_pad,), jnp.float32),
         ),
         grid=(b_pad // tile,),
-        in_specs=[fspec, fspec, wspec]
+        in_specs=[qspec, qspec]
         + [pool_spec(a) for a in scan_pool] + [pool_spec(a) for a in tiers],
-        out_specs=(ospec, qspec, qspec, qspec, qspec),
+        out_specs=(ospec, qspec, qspec),
         interpret=interpret,
-    )(feats_lo.astype(jnp.float32), feats_hi.astype(jnp.float32),
-      packed_w.astype(jnp.float32), *scan_pool, *tiers)
-    return pv[:b], cnt[:b], tot[:b], zlo[:b], zhi[:b]
+    )(zlo_q, zhi_q, *scan_pool, *tiers)
+    return pv[:b], cnt[:b], tot[:b], zlo, zhi
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("dim", "shapes", "scan_cap", "scan_iters", "use_flow",
+                     "interpret", "probe_tiers", "run_iters", "run_window",
+                     "delta_iters", "delta_window"),
+)
+def xla_range_scan(
+    feats_lo: jnp.ndarray,
+    feats_hi: jnp.ndarray,
+    packed_w: jnp.ndarray,
+    scan_pool: ScanPool,
+    tiers: Optional[TierPools] = None,
+    *,
+    dim: int,
+    shapes: Tuple[Tuple[int, int], ...] = (),
+    scan_cap: int,
+    scan_iters: int,
+    use_flow: bool = True,
+    interpret: bool = False,
+    probe_tiers: bool = False,
+    run_iters: int = 1,
+    run_window: int = 4,
+    delta_iters: int = 1,
+    delta_window: int = 4,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The range route of a compiled TPU backend: ``nf_forward_pallas``
+    (compiled by Mosaic) on both endpoints, then ``scan_merge`` as XLA
+    over the device-resident scan pool and write tiers — one jitted
+    dispatch, bit-identical to ``fused_range_scan_pallas`` (same body)
+    and to the host oracle.  Arguments as ``fused_range_scan_pallas``
+    (``interpret`` applies to the NF kernel alone); returns
+    ``(payloads, counts, totals)``."""
+    if tiers is None:
+        probe_tiers = False
+        tiers = empty_tiers()
+    zlo = positioning_keys(feats_lo, packed_w, shapes, dim, use_flow,
+                           interpret)
+    zhi = positioning_keys(feats_hi, packed_w, shapes, dim, use_flow,
+                           interpret)
+    return scan_merge(zlo, zhi, scan_pool, tiers, scan_cap=scan_cap,
+                      scan_iters=scan_iters, probe_tiers=probe_tiers,
+                      run_iters=run_iters, run_window=run_window,
+                      delta_iters=delta_iters, delta_window=delta_window)

@@ -121,9 +121,10 @@ def route(z32: np.ndarray, boundaries) -> np.ndarray:
                            side="right").astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("dim", "shapes"))
+@functools.partial(jax.jit, static_argnames=("dim", "shapes", "interpret"))
 def _route_flow(feats: jnp.ndarray, packed_w: jnp.ndarray,
-                boundaries: jnp.ndarray, *, dim: int, shapes):
+                boundaries: jnp.ndarray, *, dim: int, shapes,
+                interpret: bool = False):
     """Fused NF forward + boundary lower-bound: ONE compiled dispatch
     from raw query features to (z, shard id).  The NF runs through
     ``nf_forward_pallas`` — the same fixed-``DEFAULT_TILE`` kernel that
@@ -133,8 +134,11 @@ def _route_flow(feats: jnp.ndarray, packed_w: jnp.ndarray,
     re-materialization hazard on the sharded route)."""
     from repro.kernels.nf_forward import nf_forward_pallas
 
-    z = nf_forward_pallas(feats, packed_w, shapes, dim)
-    return z, jnp.searchsorted(boundaries, z, side="right").astype(jnp.int32)
+    z = nf_forward_pallas(feats, packed_w, shapes, dim, interpret=interpret)
+    # searchsorted-right as compare-and-count (#B <= z): P-1 boundaries
+    # are a handful of lanes, and the count needs no clamped gather
+    sid = jnp.sum(boundaries[None, :] <= z[:, None], axis=1)
+    return z, sid.astype(jnp.int32)
 
 
 def route_flow(feats: np.ndarray, packed_w, shapes,
@@ -144,8 +148,9 @@ def route_flow(feats: np.ndarray, packed_w, shapes,
     shared power-of-two bucket (``backend.pow2_batch``) so ragged
     request sizes reuse a bounded set of traces, exactly like the
     per-shard serve dispatches."""
-    from repro.kernels.backend import pow2_batch
+    from repro.kernels.backend import pow2_batch, should_interpret
 
+    interpret = should_interpret()
     feats = np.asarray(feats, np.float32)
     n = feats.shape[0]
     n_pad = pow2_batch(n)
@@ -155,11 +160,11 @@ def route_flow(feats: np.ndarray, packed_w, shapes,
         from repro.kernels.nf_forward import nf_forward_pallas
 
         z = nf_forward_pallas(jnp.asarray(feats), packed_w, shapes,
-                              feats.shape[1])
+                              feats.shape[1], interpret=interpret)
         return np.asarray(z)[:n], np.zeros(n, np.int32)
     z, sid = _route_flow(jnp.asarray(feats), packed_w,
                          jnp.asarray(boundaries), dim=feats.shape[1],
-                         shapes=tuple(shapes))
+                         shapes=tuple(shapes), interpret=interpret)
     return np.asarray(z)[:n], np.asarray(sid)[:n]
 
 

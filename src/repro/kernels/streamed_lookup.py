@@ -23,8 +23,7 @@ pool through VMEM instead of holding it resident:
    compute rides under the copy latency.  Only ``2 * stream_tile`` rows
    of the pool ever occupy VMEM — the budget bills the per-tile working
    set, not the whole pool.
-3. **what stays resident** — the query/output blocks, the NF weights,
-   the write tiers (run + delta, probed in-kernel at the final pool
+3. **what stays resident** — the query/output blocks, the write tiers (run + delta, probed in-kernel at the final pool
    tile with the same newest-copy-wins precedence as ``fused_lookup``),
    and a small *router* vector: the first key of every
    ``STREAM_ALIGN``-row slice of the pool.  The router gates each pool
@@ -47,6 +46,11 @@ any run portion inside one tile by the same backward-W / forward-3W
 argument as ``probe_pool``.  Results are bit-identical to
 ``fused_lookup_pallas`` (tree traversal + tier probe) by construction;
 the parity suite (tests/test_streamed.py) pins it.
+
+The tile probe indexes the pool slice with vector gathers, which Mosaic
+does not lower, so this rung runs in interpret mode only; a compiled TPU
+backend serves every pool size through the XLA point route
+(``flat_afli.xla_lookup``, DESIGN.md §2).
 """
 
 from __future__ import annotations
@@ -63,9 +67,10 @@ from repro.kernels.fused_lookup import (
     TOMBSTONE,
     TierPools,
     _pow2ceil,
+    empty_tiers,
     lower_bound,
-    nf_forward_lanes,
-    probe_pool,
+    merge_tiers,
+    positioning_keys,
     probe_pool_index,
     select_tile,
 )
@@ -172,12 +177,11 @@ def _ord_f32(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(i < 0, jnp.int32(-2147483648) - i, i)
 
 
-def _kernel(feat_ref, qhi_ref, qlo_ref, w_ref,
+def _kernel(z_ref, qhi_ref, qlo_ref,
             spk_ref, shi_ref, slo_ref, spv_ref, slen_ref, router_ref,
             rpk_ref, rhi_ref, rlo_ref, rpv_ref, rlen_ref,
             dpk_ref, dhi_ref, dlo_ref, dpv_ref, dlen_ref,
-            pay_ref, z_ref, bi_ref, bp_ref, *,
-            dim: int, shapes: Tuple[Tuple[int, int], ...], use_flow: bool,
+            pay_ref, bi_ref, bp_ref, *,
             stream_tile: int, window: int, use_router: bool,
             probe_tiers: bool, run_iters: int, run_window: int,
             delta_iters: int, delta_window: int):
@@ -195,14 +199,6 @@ def _kernel(feat_ref, qhi_ref, qlo_ref, w_ref,
 
     @pl.when(pt == 0)
     def _init():
-        # NF forward once per query tile (first pool tile), pinned via
-        # the z output-ref round trip exactly as in fused_lookup: one
-        # evaluation, bit-equal to the build transform's NF_TILE blocks.
-        if use_flow:
-            qk = nf_forward_lanes(feat_ref, w_ref, dim, shapes)
-        else:
-            qk = feat_ref[:, 0]
-        z_ref[...] = qk
         bi_ref[...] = jnp.full(z_ref.shape, -1, jnp.int32)
         bp_ref[...] = jnp.full(z_ref.shape, -1, jnp.int32)
 
@@ -249,27 +245,15 @@ def _kernel(feat_ref, qhi_ref, qlo_ref, w_ref,
         result = jnp.where(bi_ref[...] >= 0, bp_ref[...], -1)
         if probe_tiers:
             # identical tier merge to fused_lookup: active delta >
-            # compacted run > streamed pool, matched tombstones mask
-            # older copies then surface as misses
-            def tier_stage(phi, plo, ppv, ppk, n_t, iters, win, nmax):
-                def live(_):
-                    return probe_pool(phi, plo, ppv, n_t,
-                                      lower_bound(ppk, n_t, qkey, iters),
-                                      nmax, win, qhi, qlo)
-
-                def empty(_):
-                    return jnp.full(qkey.shape, -1, jnp.int32)
-
-                return jax.lax.cond(n_t > 0, live, empty, None)
-
-            run_pay = tier_stage(rhi_ref[...], rlo_ref[...], rpv_ref[...],
-                                 rpk_ref[...], rlen_ref[...][0], run_iters,
-                                 run_window, rpk_ref.shape[0])
-            dl_pay = tier_stage(dhi_ref[...], dlo_ref[...], dpv_ref[...],
-                                dpk_ref[...], dlen_ref[...][0], delta_iters,
-                                delta_window, dpk_ref.shape[0])
-            result = jnp.where(dl_pay != -1, dl_pay,
-                               jnp.where(run_pay != -1, run_pay, result))
+            # compacted run > streamed pool
+            result = merge_tiers(
+                result, qkey, qhi, qlo,
+                TierPools(rpk_ref[...], rhi_ref[...], rlo_ref[...],
+                          rpv_ref[...], rlen_ref[...], dpk_ref[...],
+                          dhi_ref[...], dlo_ref[...], dpv_ref[...],
+                          dlen_ref[...]),
+                run_iters=run_iters, run_window=run_window,
+                delta_iters=delta_iters, delta_window=delta_window)
         result = jnp.where(result == TOMBSTONE, -1, result)
         pay_ref[...] = result
 
@@ -334,23 +318,14 @@ def streamed_lookup_pallas(
 
     if tiers is None:
         probe_tiers = False
-        lane = jnp.zeros((_LANE,), jnp.int32)
-        tiers = TierPools(
-            run_pk=jnp.full((_LANE,), jnp.inf, jnp.float32),
-            run_hi=jnp.zeros((_LANE,), jnp.uint32),
-            run_lo=jnp.zeros((_LANE,), jnp.uint32),
-            run_pv=jnp.full((_LANE,), -1, jnp.int32), run_len=lane,
-            dl_pk=jnp.full((_LANE,), jnp.inf, jnp.float32),
-            dl_hi=jnp.zeros((_LANE,), jnp.uint32),
-            dl_lo=jnp.zeros((_LANE,), jnp.uint32),
-            dl_pv=jnp.full((_LANE,), -1, jnp.int32), dl_len=lane,
-        )
-
-    b = feats.shape[0]
-    tile = select_tile(b, use_flow, tile, interpret)
+        tiers = empty_tiers()
+    z = positioning_keys(feats, packed_w, shapes, dim, use_flow, interpret)
+    b = z.shape[0]
+    tile = select_tile(b, tile, interpret)
     b_pad = ((b + tile - 1) // tile) * tile
+    zq = z
     if b_pad != b:
-        feats = jnp.pad(feats, ((0, b_pad - b), (0, 0)))
+        zq = jnp.pad(z, (0, b_pad - b))
         qhi = jnp.pad(qhi, (0, b_pad - b))
         qlo = jnp.pad(qlo, (0, b_pad - b))
 
@@ -358,34 +333,30 @@ def streamed_lookup_pallas(
     # blocks' index maps ignore axis 1 so they stay resident across the
     # whole pool sweep; the pool blocks revolve and get double-buffered
     qspec = pl.BlockSpec((tile,), lambda q, t: (q,))
-    fspec = pl.BlockSpec((tile, feats.shape[1]), lambda q, t: (q, 0))
-    wspec = pl.BlockSpec((1, packed_w.shape[1]), lambda q, t: (0, 0))
     sspec = pl.BlockSpec((stream_tile,), lambda q, t: (t,))
 
     def resident(a):
         return pl.BlockSpec(a.shape, lambda q, t: (0,) * a.ndim)
 
-    pay, z, _bi, _bp = pl.pallas_call(
+    pay, _bi, _bp = pl.pallas_call(
         functools.partial(
-            _kernel, dim=dim, shapes=shapes, use_flow=use_flow,
-            stream_tile=stream_tile, window=window, use_router=use_router,
-            probe_tiers=probe_tiers, run_iters=run_iters,
-            run_window=run_window, delta_iters=delta_iters,
-            delta_window=delta_window,
+            _kernel, stream_tile=stream_tile, window=window,
+            use_router=use_router, probe_tiers=probe_tiers,
+            run_iters=run_iters, run_window=run_window,
+            delta_iters=delta_iters, delta_window=delta_window,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad,), jnp.float32),
             jax.ShapeDtypeStruct((b_pad,), jnp.int32),
             jax.ShapeDtypeStruct((b_pad,), jnp.int32),
         ),
         grid=(b_pad // tile, n_pt),
-        in_specs=[fspec, qspec, qspec, wspec,
+        in_specs=[qspec, qspec, qspec,
                   sspec, sspec, sspec, sspec,
                   resident(pool.plen), resident(router)]
         + [resident(a) for a in tiers],
-        out_specs=(qspec, qspec, qspec, qspec),
+        out_specs=(qspec, qspec, qspec),
         interpret=interpret,
-    )(feats.astype(jnp.float32), qhi, qlo, packed_w.astype(jnp.float32),
-      pool.pk, pool.hi, pool.lo, pool.pv, pool.plen, router, *tiers)
-    return pay[:b], z[:b]
+    )(zq, qhi, qlo, pool.pk, pool.hi, pool.lo, pool.pv, pool.plen, router,
+      *tiers)
+    return pay[:b], z

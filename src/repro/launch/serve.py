@@ -97,6 +97,7 @@ def run_index(args) -> None:
 
 def main():
     from repro.configs import arch_names
+    from repro.kernels.backend import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="lm", choices=("lm", "index"),
@@ -120,6 +121,7 @@ def main():
                     choices=("", "fallback", "stall", "errors"),
                     help="replay the trace under an injected fault")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.requests is None:
         args.requests = 12 if args.mode == "lm" else 2_000
     if args.mode == "lm":

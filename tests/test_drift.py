@@ -16,6 +16,7 @@ Three layers, matching the module split:
   never stall; only the healthy mode may swap.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -191,6 +192,33 @@ def test_manager_trainer_fault_mid_training():
     mgr.tick()  # second step raises
     assert mgr.state == ReflowManager.IDLE
     assert mgr.retrain_failures == 1 and calls["apply"] == 0
+
+
+@pytest.mark.parametrize("err", [
+    NotImplementedError("Unimplemented primitive in Pallas TPU lowering: "
+                        "optimization_barrier"),
+    jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU "
+                               "kernel"),
+], ids=["lowering", "device-runtime"])
+@pytest.mark.parametrize("stage", ["serving_tail", "train_factory", "step",
+                                   "evaluate", "apply"])
+def test_manager_device_error_propagates(stage, err):
+    """A compile or device failure inside a re-flow step is a broken
+    build, not a failed episode: it propagates (like a lock-discipline
+    violation) instead of being counted and backed off."""
+    def _raise(*_a, **_k):
+        raise err
+
+    trainer = _StubTrainer(steps=1)
+    if stage == "step":
+        trainer.step = _raise
+    mgr, _, _ = _armed_manager(trainer=trainer)
+    if stage != "step":
+        setattr(mgr, stage, _raise)
+    with pytest.raises(type(err)):
+        for _ in range(3):
+            mgr.tick()
+    assert mgr.retrain_failures == 0
 
 
 def test_manager_busy_apply_retries():

@@ -178,11 +178,17 @@ def test_overload_sheds_and_stays_exact():
     orc = _Oracle(oracle)
     fe = FrontEnd(nfl, FrontEndConfig(max_batch=32, batch_timeout_s=1e-4))
     fe.on_batch_dispatched = orc.hook
-    # prime the service-time model so admission predictions are live
-    for _ in range(3):
+    # prime the service-time model so admission predictions are live,
+    # and time a batch: the backlog (25 batches of 32) is set against a
+    # deadline of 8 batch times, an overload however fast the route is
+    batch_s = []
+    for _ in range(6):
+        t0 = time.perf_counter()
         nfl.lookup_batch(rng.choice(keys, 32, replace=False))
+        batch_s.append(time.perf_counter() - t0)
+    deadline_s = 8 * float(np.median(batch_s[3:]))
     reqs = [ServiceRequest(i, "point", float(rng.choice(keys)),
-                           deadline_s=0.02) for i in range(800)]
+                           deadline_s=deadline_s) for i in range(800)]
     _submit_drain(fe, reqs)
     _assert_terminal_exactly_once(fe, reqs)
     assert fe.counters["shed"] + fe.counters["expired"] > 0

@@ -229,16 +229,16 @@ def test_tiled_grid_matches_single_step():
 
 def test_select_tile_policy():
     from repro.kernels.fused_lookup import (DEFAULT_TILE, INTERPRET_TILE,
-                                            NF_TILE, select_tile)
+                                            select_tile)
 
-    # no-flow: pow2-bucketed, capped so large batches become grids
-    assert select_tile(100, False, interpret=True) == 128
-    assert select_tile(8_192, False, interpret=True) == INTERPRET_TILE
-    assert select_tile(8_192, False, interpret=False) == DEFAULT_TILE
-    # flow: pinned to whole NF_TILE multiples
-    assert select_tile(100, True, interpret=True) == NF_TILE
-    assert select_tile(8_192, True, tile=700, interpret=True) \
-        == 2 * NF_TILE
+    # pow2-bucketed, capped so large batches become grids
+    assert select_tile(100, interpret=True) == 128
+    assert select_tile(8_192, interpret=True) == INTERPRET_TILE
+    assert select_tile(8_192, interpret=False) == DEFAULT_TILE
+    # an explicit tile is capped by the batch bucket, lane-aligned when
+    # compiled (the NF runs before the grid, so the flow pins nothing)
+    assert select_tile(100, tile=700, interpret=True) == 128
+    assert select_tile(100, tile=64, interpret=False) == 128
 
 
 # ------------------------------------------------------------ preallocation
