@@ -52,6 +52,7 @@ from repro.kernels.fused_lookup import (
     merge_tiers,
     positioning_keys,
 )
+from repro.obs import span
 
 __all__ = ["FlatAFLI", "FlatAFLIConfig", "FlatArrays", "TOMBSTONE"]
 
@@ -1103,33 +1104,35 @@ class FlatAFLI:
                                   "retraced": False}
             return np.full(pk32.shape[0], -1, np.int32), pk32.shape[0]
 
-        # pad to power-of-two buckets: ragged request batches would
-        # recompile the kernel / traversal loop per distinct size
         from repro.kernels.backend import pow2_batch
 
-        n = pk32.shape[0]
-        n_pad = pow2_batch(n)
-        if n_pad != n:
-            pk32 = np.pad(pk32, (0, n_pad - n))
-            hi = np.pad(hi, (0, n_pad - n))
-            lo = np.pad(lo, (0, n_pad - n))
-        res, _z, self.last_dispatch = ops.fused_lookup(
-            self.arrays if arrays is None else arrays,
-            self._kernel_pools if pools is None else pools,
-            jnp.asarray(np.ascontiguousarray(pk32).reshape(-1, 1)),
-            jnp.asarray(hi), jnp.asarray(lo), flow=None,
-            max_depth=self._depth_static() if max_depth is None else max_depth,
-            dense_iters=self.cfg.dense_search_iters,
-            bucket_cap=self.cfg.max_bucket,
-            dense_window=(self._dense_window_static()
-                          if dense_window is None else dense_window),
-            tiers=self._tier_pack if tiers else None,
-            stream=self._stream_arg(
-                live=arrays is None and pools is None and tiers),
-            vmem_budget=self.cfg.vmem_budget
-            if self.cfg.use_fused_kernel else 0,
-            sync=False,
-        )
+        with span("afli.point.enqueue"):
+            # pad to power-of-two buckets: ragged request batches would
+            # recompile the kernel / traversal loop per distinct size
+            n = pk32.shape[0]
+            n_pad = pow2_batch(n)
+            if n_pad != n:
+                pk32 = np.pad(pk32, (0, n_pad - n))
+                hi = np.pad(hi, (0, n_pad - n))
+                lo = np.pad(lo, (0, n_pad - n))
+            res, _z, self.last_dispatch = ops.fused_lookup(
+                self.arrays if arrays is None else arrays,
+                self._kernel_pools if pools is None else pools,
+                jnp.asarray(np.ascontiguousarray(pk32).reshape(-1, 1)),
+                jnp.asarray(hi), jnp.asarray(lo), flow=None,
+                max_depth=(self._depth_static() if max_depth is None
+                           else max_depth),
+                dense_iters=self.cfg.dense_search_iters,
+                bucket_cap=self.cfg.max_bucket,
+                dense_window=(self._dense_window_static()
+                              if dense_window is None else dense_window),
+                tiers=self._tier_pack if tiers else None,
+                stream=self._stream_arg(
+                    live=arrays is None and pools is None and tiers),
+                vmem_budget=self.cfg.vmem_budget
+                if self.cfg.use_fused_kernel else 0,
+                sync=False,
+            )
         return res, n
 
     def _device_lookup(self, pk32: np.ndarray, hi: np.ndarray,
@@ -1167,12 +1170,13 @@ class FlatAFLI:
         workload: a re-insert-heavy stream cannot ratchet the kernel's
         static scan window mid-serving (§11 zero-retrace), and the probe
         semantics are unchanged (the newest copy is the only copy)."""
-        (self._delta_pk, self._delta_hi,
-         self._delta_lo, self._delta_pv) = _dedup_newest(
-            np.concatenate([self._delta_pk, pk]),
-            np.concatenate([self._delta_hi, hi]),
-            np.concatenate([self._delta_lo, lo]),
-            np.concatenate([self._delta_pv, pv.astype(np.int32)]))
+        with span("afli.insert.delta"):
+            (self._delta_pk, self._delta_hi,
+             self._delta_lo, self._delta_pv) = _dedup_newest(
+                np.concatenate([self._delta_pk, pk]),
+                np.concatenate([self._delta_hi, hi]),
+                np.concatenate([self._delta_lo, lo]),
+                np.concatenate([self._delta_pv, pv.astype(np.int32)]))
         self._serving.mark_delta_dirty()
         self._sync_tiers()
 
@@ -1193,14 +1197,15 @@ class FlatAFLI:
         """Retire the full active delta into the compacted run."""
         if not self._delta_pk.shape[0]:
             return
-        self._append_run(self._delta_pk, self._delta_hi,
-                         self._delta_lo, self._delta_pv)
-        self._delta_pk = np.empty(0, np.float32)
-        self._delta_hi = np.empty(0, np.uint32)
-        self._delta_lo = np.empty(0, np.uint32)
-        self._delta_pv = np.empty(0, np.int32)
-        self._serving.mark_delta_dirty()
-        self._sync_tiers()
+        with span("afli.run_merge"):
+            self._append_run(self._delta_pk, self._delta_hi,
+                             self._delta_lo, self._delta_pv)
+            self._delta_pk = np.empty(0, np.float32)
+            self._delta_hi = np.empty(0, np.uint32)
+            self._delta_lo = np.empty(0, np.uint32)
+            self._delta_pv = np.empty(0, np.int32)
+            self._serving.mark_delta_dirty()
+            self._sync_tiers()
 
     # ------------------------------------------------------------- lookup
     def _tier_state(self):
@@ -1264,7 +1269,8 @@ class FlatAFLI:
         tier_state = self._tier_state()
 
         def finish() -> np.ndarray:
-            res = np.asarray(res_dev)[:n]
+            with span("afli.point.wait"):
+                res = np.asarray(res_dev)[:n]
             if host_probe:
                 return self._probe_tiers_at(tier_state, res, q32, hi, lo)
             return res
@@ -1344,32 +1350,34 @@ class FlatAFLI:
 
         ik64 = np.asarray(ikeys, dtype=np.float64)
         hi, lo = split_key_bits(ik64)
-        n = feats.shape[0]
-        n_pad = pow2_batch(n)
-        pf, phi, plo = feats, hi, lo
-        if n_pad != n:
-            pf = np.pad(feats, ((0, n_pad - n), (0, 0)))
-            phi = np.pad(hi, (0, n_pad - n))
-            plo = np.pad(lo, (0, n_pad - n))
-        res_dev, z_dev, self.last_dispatch = ops.fused_lookup(
-            self.arrays, self._kernel_pools,
-            jnp.asarray(pf, jnp.float32), jnp.asarray(phi),
-            jnp.asarray(plo), flow=(packed_w, shapes),
-            max_depth=self._depth_static(),
-            dense_iters=self.cfg.dense_search_iters,
-            bucket_cap=self.cfg.max_bucket,
-            dense_window=self._dense_window_static(),
-            tiers=self._tier_pack,
-            stream=self._stream_arg(live=True),
-            vmem_budget=self.cfg.vmem_budget
-            if self.cfg.use_fused_kernel else 0,
-            sync=False,
-        )
+        with span("afli.point.enqueue"):
+            n = feats.shape[0]
+            n_pad = pow2_batch(n)
+            pf, phi, plo = feats, hi, lo
+            if n_pad != n:
+                pf = np.pad(feats, ((0, n_pad - n), (0, 0)))
+                phi = np.pad(hi, (0, n_pad - n))
+                plo = np.pad(lo, (0, n_pad - n))
+            res_dev, z_dev, self.last_dispatch = ops.fused_lookup(
+                self.arrays, self._kernel_pools,
+                jnp.asarray(pf, jnp.float32), jnp.asarray(phi),
+                jnp.asarray(plo), flow=(packed_w, shapes),
+                max_depth=self._depth_static(),
+                dense_iters=self.cfg.dense_search_iters,
+                bucket_cap=self.cfg.max_bucket,
+                dense_window=self._dense_window_static(),
+                tiers=self._tier_pack,
+                stream=self._stream_arg(live=True),
+                vmem_budget=self.cfg.vmem_budget
+                if self.cfg.use_fused_kernel else 0,
+                sync=False,
+            )
         host_probe = self.last_dispatch.get("host_probe", True)
         tier_state = self._tier_state()
 
         def finish() -> np.ndarray:
-            res = np.asarray(res_dev)[:n]
+            with span("afli.point.wait"):
+                res = np.asarray(res_dev)[:n]
             if host_probe:
                 return self._probe_tiers_at(tier_state, res,
                                             np.asarray(z_dev)[:n], hi, lo)
@@ -1766,16 +1774,19 @@ class FlatAFLI:
         return True
 
     def _fold_tick(self, budget: int) -> None:
-        if self._fold is not None:
-            # §16 fault-injection hook: a FaultPlan with fold_stall_s
-            # set models a slow fold, stretching the tier-resident window
+        if self._fold is None:
+            return
+        with span("afli.fold_tick"):
+            # §16 fault-injection hook: a FaultPlan with fold_stall_s set
+            # models a slow fold, stretching the tier-resident window
             from repro.kernels import ops
 
             ops.fault_stall("fold")
-        if self._fold is not None and self._fold.tick(budget):
-            # swapped in; apply any delta merge deferred during the fold
-            if self._delta_pk.shape[0] > self.cfg.delta_cap:
-                self._merge_delta_into_run()
+            if self._fold.tick(budget):
+                # swapped in; apply any delta merge deferred during the
+                # fold
+                if self._delta_pk.shape[0] > self.cfg.delta_cap:
+                    self._merge_delta_into_run()
 
     def rebuild(self) -> None:
         """Fold every write tier into the static structure synchronously

@@ -53,6 +53,7 @@ from repro.core.feature import expand_features
 from repro.core.flat_afli import FlatAFLI, FlatAFLIConfig
 from repro.core.flow import FlowConfig, transform_keys
 from repro.core.train_flow import FlowTrainConfig, FlowTrainer, train_flow
+from repro.obs import span
 
 __all__ = ["NFL", "NFLConfig"]
 
@@ -338,6 +339,13 @@ class NFL:
             self._reshard.observe(int(n_keys))
             self._reshard.tick()
 
+    def _features(self, keys: np.ndarray) -> np.ndarray:
+        """Host feature expansion of serve-path query keys, the fused
+        NF's input."""
+        with span("nfl.features"):
+            return expand_features(keys, self.normalizer, self.cfg.flow.dim,
+                                   self.cfg.flow.theta, dtype=np.float32)
+
     def _pkeys(self, keys: np.ndarray) -> np.ndarray:
         """Positioning keys for a batch of query keys (online NF inference)."""
         keys = np.asarray(keys, dtype=np.float64)
@@ -348,26 +356,25 @@ class NFL:
     # ------------------------------------------------------------ batch ops
     def lookup_batch(self, keys: np.ndarray) -> np.ndarray:
         """Batched point lookups; -1 marks not-found."""
-        keys = np.asarray(keys, dtype=np.float64)
-        if self.cfg.backend == "flat":
-            if not self.use_flow:
-                res = self.index.lookup_batch(keys)
+        with span("nfl.lookup"):
+            keys = np.asarray(keys, dtype=np.float64)
+            if self.cfg.backend == "flat":
+                if not self.use_flow:
+                    res = self.index.lookup_batch(keys)
+                    self._reshard_note(keys.shape[0])
+                    return res
+                # fused single dispatch: NF forward + traversal in one kernel
+                res = self.index.lookup_batch_flow(
+                    self._features(keys), keys, self._packed_w, self._shapes)
                 self._reshard_note(keys.shape[0])
                 return res
-            # fused single dispatch: NF forward + traversal in one kernel
-            feats = expand_features(keys, self.normalizer, self.cfg.flow.dim,
-                                    self.cfg.flow.theta, dtype=np.float32)
-            res = self.index.lookup_batch_flow(feats, keys, self._packed_w,
-                                               self._shapes)
-            self._reshard_note(keys.shape[0])
-            return res
-        pkeys = self._pkeys(keys)
-        out = np.empty(keys.shape[0], dtype=np.int64)
-        lookup = self.index.lookup
-        for i in range(keys.shape[0]):
-            r = lookup(float(pkeys[i]), float(keys[i]))
-            out[i] = -1 if r is None else r
-        return out
+            pkeys = self._pkeys(keys)
+            out = np.empty(keys.shape[0], dtype=np.int64)
+            lookup = self.index.lookup
+            for i in range(keys.shape[0]):
+                r = lookup(float(pkeys[i]), float(keys[i]))
+                out[i] = -1 if r is None else r
+            return out
 
     def lookup_batch_async(self, keys: np.ndarray):
         """Dispatch a batched point lookup without blocking; returns a
@@ -380,40 +387,39 @@ class NFL:
         state each batch was dispatched into.  The AFLI backend has no
         device path — the lookup runs eagerly and the finisher just
         hands the result back."""
-        keys = np.asarray(keys, dtype=np.float64)
-        if self.cfg.backend == "flat":
-            if not self.use_flow:
-                finish = self.index.lookup_batch_async(keys)
-            else:
-                feats = expand_features(keys, self.normalizer,
-                                        self.cfg.flow.dim,
-                                        self.cfg.flow.theta,
-                                        dtype=np.float32)
-                finish = self.index.lookup_batch_flow_async(
-                    feats, keys, self._packed_w, self._shapes)
-            # kernels are already in flight: the reshard control tick
-            # overlaps the device work it is charged to
-            self._reshard_note(keys.shape[0])
-            return finish
-        res = self.lookup_batch(keys)
-        return lambda: res
+        with span("nfl.lookup"):
+            keys = np.asarray(keys, dtype=np.float64)
+            if self.cfg.backend == "flat":
+                if not self.use_flow:
+                    finish = self.index.lookup_batch_async(keys)
+                else:
+                    finish = self.index.lookup_batch_flow_async(
+                        self._features(keys), keys, self._packed_w,
+                        self._shapes)
+                # kernels are already in flight: the reshard control tick
+                # overlaps the device work it is charged to
+                self._reshard_note(keys.shape[0])
+                return finish
+            res = self.lookup_batch(keys)
+            return lambda: res
 
     def insert_batch(self, keys: np.ndarray, payloads: np.ndarray) -> None:
-        keys = np.asarray(keys, dtype=np.float64)
-        payloads = np.asarray(payloads, dtype=np.int64)
-        pkeys = self._pkeys(keys)
-        if self.cfg.backend == "flat":
-            self.index.insert_batch(
-                pkeys, payloads, ikeys=keys if self.use_flow else None)
-            if self._drift is not None:
-                with self._telemetry_lock:
-                    self._drift.observe(keys)
-                    self._reflow.tick()
-            self._reshard_note(keys.shape[0])
-            return
-        insert = self.index.insert
-        for i in range(keys.shape[0]):
-            insert(float(pkeys[i]), int(payloads[i]), float(keys[i]))
+        with span("nfl.insert"):
+            keys = np.asarray(keys, dtype=np.float64)
+            payloads = np.asarray(payloads, dtype=np.int64)
+            pkeys = self._pkeys(keys)
+            if self.cfg.backend == "flat":
+                self.index.insert_batch(
+                    pkeys, payloads, ikeys=keys if self.use_flow else None)
+                if self._drift is not None:
+                    with self._telemetry_lock:
+                        self._drift.observe(keys)
+                        self._reflow.tick()
+                self._reshard_note(keys.shape[0])
+                return
+            insert = self.index.insert
+            for i in range(keys.shape[0]):
+                insert(float(pkeys[i]), int(payloads[i]), float(keys[i]))
 
     def update_batch(self, keys: np.ndarray, payloads: np.ndarray) -> np.ndarray:
         keys = np.asarray(keys, dtype=np.float64)
@@ -467,24 +473,17 @@ class NFL:
             raise NotImplementedError(
                 "range scans are served by the flat backend's fused "
                 "range-scan kernel; use backend='flat'")
-        lo_keys = np.asarray(lo_keys, dtype=np.float64)
-        hi_keys = np.asarray(hi_keys, dtype=np.float64)
-        if not self.use_flow:
-            res = self.index.scan_batch(lo_keys, hi_keys, cap=cap)
-        else:
-            feats_lo = expand_features(lo_keys, self.normalizer,
-                                       self.cfg.flow.dim,
-                                       self.cfg.flow.theta,
-                                       dtype=np.float32)
-            feats_hi = expand_features(hi_keys, self.normalizer,
-                                       self.cfg.flow.dim,
-                                       self.cfg.flow.theta,
-                                       dtype=np.float32)
-            res = self.index.scan_batch_flow(feats_lo, feats_hi,
-                                             self._packed_w, self._shapes,
-                                             cap=cap)
-        self._reshard_note(lo_keys.shape[0])
-        return res
+        with span("nfl.scan"):
+            lo_keys = np.asarray(lo_keys, dtype=np.float64)
+            hi_keys = np.asarray(hi_keys, dtype=np.float64)
+            if not self.use_flow:
+                res = self.index.scan_batch(lo_keys, hi_keys, cap=cap)
+            else:
+                res = self.index.scan_batch_flow(
+                    self._features(lo_keys), self._features(hi_keys),
+                    self._packed_w, self._shapes, cap=cap)
+            self._reshard_note(lo_keys.shape[0])
+            return res
 
     # established range-query spelling alongside the batched name
     lookup_range = scan_batch

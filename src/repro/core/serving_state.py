@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
+
 __all__ = ["ServingState", "DeviceTier", "pow2_bucket"]
 
 _LANE = 128
@@ -368,12 +370,15 @@ class ServingState:
         delta append never pays the window scan over the (unchanged,
         much larger) run mirror.  Called eagerly from the write path so
         reads never pay it."""
-        if self._run_dirty:
-            self.run.refresh(*run_mirror())
-            self._run_dirty = False
-        if self._delta_dirty:
-            self.delta.refresh(*delta_mirror())
-            self._delta_dirty = False
+        if not (self._run_dirty or self._delta_dirty):
+            return
+        with span("afli.tier_sync"):
+            if self._run_dirty:
+                self.run.refresh(*run_mirror())
+                self._run_dirty = False
+            if self._delta_dirty:
+                self.delta.refresh(*delta_mirror())
+                self._delta_dirty = False
 
     def tier_pack(self):
         """The resident ``TierPack`` for the in-kernel tier probe

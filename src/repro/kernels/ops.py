@@ -28,6 +28,7 @@ from repro.kernels.backend import resolve_interpret, should_interpret
 from repro.kernels.nf_forward import nf_forward_pallas, pack_flow_weights
 from repro.kernels.index_probe import index_probe_pallas
 from repro.kernels.flash_decode import flash_decode_pallas
+from repro.obs import span
 
 __all__ = [
     "should_interpret",
@@ -749,7 +750,8 @@ def fused_range_scan(scan_pack, tiers, feats_lo, feats_hi, *, flow=None,
             delta_iters=tiers.delta_iters if have_tiers else 1,
             delta_window=tiers.delta_window if have_tiers else 4,
         )
-        pv, cnt, tot = np.asarray(pv), np.asarray(cnt), np.asarray(tot)
+        with span("afli.scan.wait"):
+            pv, cnt, tot = np.asarray(pv), np.asarray(cnt), np.asarray(tot)
         retraced = serving_cache_size() > cache_before
         n_trunc = int((tot > scan_cap).sum())
         _bump(scan_xla_count=1, retrace_count=int(retraced),

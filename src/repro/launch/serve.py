@@ -92,7 +92,8 @@ def run_index(args) -> None:
     lat = s["latency_ontime"]
     print(f"  on-time latency p50={lat['p50_ns'] / 1e6:.2f}ms "
           f"p99={lat['p99_ns'] / 1e6:.2f}ms "
-          f"p999={lat['p999_ns'] / 1e6:.2f}ms")
+          f"p999={lat['p999_ns'] / 1e6:.2f}ms "
+          f"(newest {lat['n']} on-time requests)")
 
 
 def main():

@@ -61,11 +61,14 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.kernels.ops import TransientDispatchError
+from repro.obs import span
 
 __all__ = ["COMPLETED", "SHED", "EXPIRED", "FrontEnd", "FrontEndConfig",
-           "ServiceRequest"]
+           "LATENCY_WINDOW", "ServiceRequest"]
 
 COMPLETED, SHED, EXPIRED = "completed", "shed", "expired"
+# the latency telemetry keeps the newest this many requests
+LATENCY_WINDOW = 65536
 _TERMINAL = (COMPLETED, SHED, EXPIRED)
 _OPS = ("point", "range", "insert", "delete")
 
@@ -118,6 +121,12 @@ class FrontEnd:
     deployment shape of the seed ``ContinuousBatcher`` as well; the
     telemetry it reads (``NFL.dispatch_stats``, ops counters) *is*
     safe against the §14 background machinery.
+
+    ``counters["queue_wait_ns"]`` sums, over dispatched requests, the
+    time from ``submit`` to the start of the batch's dispatch.  While a
+    profiler trace runs, the loop records the spans ``fe.form``,
+    ``fe.dispatch``, ``fe.gather`` and ``fe.resolve`` (``repro.obs``),
+    each with its batch number.
     """
 
     def __init__(self, nfl, cfg: FrontEndConfig | None = None,
@@ -126,14 +135,17 @@ class FrontEnd:
         self.cfg = cfg or FrontEndConfig()
         self.clock = clock
         self.queue: Deque[ServiceRequest] = deque()
-        # in-flight read batches: (op, requests, t_dispatch, finisher)
+        # in-flight read batches: (op, requests, t_dispatch, finisher,
+        # batch number)
         self.inflight: Deque[Tuple[str, List[ServiceRequest], float,
-                                   Callable[[], np.ndarray]]] = deque()
+                                   Callable[[], np.ndarray], int]] = deque()
         self.counters: Dict[str, int] = {
             "admitted": 0, "completed": 0, "shed": 0, "expired": 0,
             "completed_late": 0, "batches": 0, "dispatched_requests": 0,
-            "retries": 0, "retry_giveups": 0,
+            "retries": 0, "retry_giveups": 0, "queue_wait_ns": 0,
         }
+        # sum of t_submit over the batch _form_batch last formed
+        self._formed_t_submit = 0.0
         self.reasons: Dict[str, int] = {
             "shed-admission": 0, "shed-error": 0,
             "expired-queued": 0, "expired-late": 0,
@@ -142,9 +154,10 @@ class FrontEnd:
         # dispatch itself) — seeded pessimistically, corrected fast
         self._svc_batch_s: Dict[str, float] = {op: 5e-3 for op in _OPS}
         # latency of every request that was actually served (reads that
-        # came back + writes that executed), late or not
-        self._served_lat: List[float] = []
-        self._ontime_lat: List[float] = []
+        # came back + writes that executed), late or not: the newest
+        # LATENCY_WINDOW of each
+        self._served_lat: Deque[float] = deque(maxlen=LATENCY_WINDOW)
+        self._ontime_lat: Deque[float] = deque(maxlen=LATENCY_WINDOW)
         # test/oracle seam: called once per dispatched batch, in
         # dispatch order, right at the dispatch point
         self.on_batch_dispatched: Optional[
@@ -186,7 +199,7 @@ class FrontEnd:
 
     def _backlog_s(self) -> float:
         return sum(self._predict_s(op, len(reqs))
-                   for op, reqs, _, _ in self.inflight)
+                   for op, reqs, _, _, _ in self.inflight)
 
     # ---------------------------------------------------------- batching
     def _flush_due(self, now: float, drain: bool) -> bool:
@@ -202,24 +215,28 @@ class FrontEnd:
         (predicted completion past deadline)."""
         batch: List[ServiceRequest] = []
         op = None
-        backlog = self._backlog_s()
-        while self.queue and len(batch) < self.cfg.max_batch:
-            req = self.queue[0]
-            if op is not None and req.op != op:
-                break
-            self.queue.popleft()
-            if (self.cfg.expire_queued
-                    and now > req.t_submit + req.deadline_s):
-                self._resolve(req, EXPIRED, now, reason="queued")
-                continue
-            if self.cfg.admission:
-                pred = backlog + self._predict_s(req.op, len(batch) + 1)
-                if (now + self.cfg.slo_margin * pred
-                        > req.t_submit + req.deadline_s):
-                    self._resolve(req, SHED, now, reason="admission")
+        t_submit = 0.0
+        with span("fe.form", batch=self.counters["batches"] + 1):
+            backlog = self._backlog_s()
+            while self.queue and len(batch) < self.cfg.max_batch:
+                req = self.queue[0]
+                if op is not None and req.op != op:
+                    break
+                self.queue.popleft()
+                if (self.cfg.expire_queued
+                        and now > req.t_submit + req.deadline_s):
+                    self._resolve(req, EXPIRED, now, reason="queued")
                     continue
-            op = req.op
-            batch.append(req)
+                if self.cfg.admission:
+                    pred = backlog + self._predict_s(req.op, len(batch) + 1)
+                    if (now + self.cfg.slo_margin * pred
+                            > req.t_submit + req.deadline_s):
+                        self._resolve(req, SHED, now, reason="admission")
+                        continue
+                op = req.op
+                batch.append(req)
+                t_submit += req.t_submit
+        self._formed_t_submit = t_submit
         return batch
 
     # ---------------------------------------------------------- dispatch
@@ -239,68 +256,77 @@ class FrontEnd:
                 delay *= 2.0
 
     def _dispatch(self, batch: List[ServiceRequest]) -> None:
+        """Dispatch a batch ``_form_batch`` formed."""
         op = batch[0].op
-        self.counters["batches"] += 1
-        self.counters["dispatched_requests"] += len(batch)
+        c = self.counters
+        c["batches"] += 1
+        c["dispatched_requests"] += len(batch)
         t0 = self.clock()
-        try:
-            if op == "point":
+        c["queue_wait_ns"] += round(
+            (len(batch) * t0 - self._formed_t_submit) * 1e9)
+        bno = c["batches"]
+        with span("fe.dispatch", batch=bno):
+            try:
+                if op == "point":
+                    keys = np.array([r.key for r in batch], np.float64)
+                    fin = self._with_retry(
+                        lambda: self.nfl.lookup_batch_async(keys))
+                    self._hook(op, batch)
+                    self.inflight.append((op, batch, t0, fin, bno))
+                    return
+                if op == "range":
+                    lo = np.array([r.key for r in batch], np.float64)
+                    hi = np.array([r.hi for r in batch], np.float64)
+                    pv, cnt, tot = self._with_retry(
+                        lambda: self.nfl.scan_batch(lo, hi))
+                    self._hook(op, batch)
+                    now = self.clock()
+                    self._observe_s(op, len(batch), now - t0)
+                    with span("fe.resolve", batch=bno):
+                        for i, r in enumerate(batch):
+                            r.result = (pv[i, :cnt[i]].tolist(), int(tot[i]))
+                            self._finish_read(r, now)
+                    return
+                if op == "insert":
+                    keys = np.array([r.key for r in batch], np.float64)
+                    pv = np.array([r.payload for r in batch], np.int64)
+                    self._with_retry(lambda: self.nfl.insert_batch(keys, pv))
+                    self._hook(op, batch)
+                    self._finish_writes(batch, t0, bno, ok=None)
+                    return
+                # delete
                 keys = np.array([r.key for r in batch], np.float64)
-                fin = self._with_retry(
-                    lambda: self.nfl.lookup_batch_async(keys))
+                ok = self._with_retry(lambda: self.nfl.delete_batch(keys))
                 self._hook(op, batch)
-                self.inflight.append((op, batch, t0, fin))
-                return
-            if op == "range":
-                lo = np.array([r.key for r in batch], np.float64)
-                hi = np.array([r.hi for r in batch], np.float64)
-                pv, cnt, tot = self._with_retry(
-                    lambda: self.nfl.scan_batch(lo, hi))
-                self._hook(op, batch)
+                self._finish_writes(batch, t0, bno, ok=ok)
+            except TransientDispatchError:
+                # retry budget exhausted: the batch never dispatched, so no
+                # state changed — resolve every request as shed("error")
                 now = self.clock()
-                self._observe_s(op, len(batch), now - t0)
-                for i, r in enumerate(batch):
-                    r.result = (pv[i, :cnt[i]].tolist(), int(tot[i]))
-                    self._finish_read(r, now)
-                return
-            if op == "insert":
-                keys = np.array([r.key for r in batch], np.float64)
-                pv = np.array([r.payload for r in batch], np.int64)
-                self._with_retry(lambda: self.nfl.insert_batch(keys, pv))
-                self._hook(op, batch)
-                self._finish_writes(batch, t0, ok=None)
-                return
-            # delete
-            keys = np.array([r.key for r in batch], np.float64)
-            ok = self._with_retry(lambda: self.nfl.delete_batch(keys))
-            self._hook(op, batch)
-            self._finish_writes(batch, t0, ok=ok)
-        except TransientDispatchError:
-            # retry budget exhausted: the batch never dispatched, so no
-            # state changed — resolve every request as shed("error")
-            now = self.clock()
-            self.counters["retry_giveups"] += 1
-            for r in batch:
-                self._resolve(r, SHED, now, reason="error")
+                self.counters["retry_giveups"] += 1
+                for r in batch:
+                    self._resolve(r, SHED, now, reason="error")
 
     def _hook(self, op: str, batch: List[ServiceRequest]) -> None:
         if self.on_batch_dispatched is not None:
             self.on_batch_dispatched(op, batch)
 
     def _finish_writes(self, batch: List[ServiceRequest], t0: float,
-                       ok) -> None:
+                       bno: int, ok) -> None:
         now = self.clock()
         self._observe_s(batch[0].op, len(batch), now - t0)
-        for i, r in enumerate(batch):
-            r.result = True if ok is None else bool(ok[i])
-            late = now > r.t_submit + r.deadline_s
-            # a dispatched write always completes — its effect is in the
-            # index — but a late one is an SLO miss, not goodput
-            self._resolve(r, COMPLETED, now, reason="late" if late else "")
-            self.counters["completed_late"] += int(late)
-            self._served_lat.append(r.latency_s)
-            if not late:
-                self._ontime_lat.append(r.latency_s)
+        with span("fe.resolve", batch=bno):
+            for i, r in enumerate(batch):
+                r.result = True if ok is None else bool(ok[i])
+                late = now > r.t_submit + r.deadline_s
+                # a dispatched write always completes — its effect is in
+                # the index — but a late one is an SLO miss, not goodput
+                self._resolve(r, COMPLETED, now,
+                              reason="late" if late else "")
+                self.counters["completed_late"] += int(late)
+                self._served_lat.append(r.latency_s)
+                if not late:
+                    self._ontime_lat.append(r.latency_s)
 
     def _finish_read(self, r: ServiceRequest, now: float) -> None:
         self._served_lat.append(now - r.t_submit)
@@ -311,13 +337,15 @@ class FrontEnd:
             self._ontime_lat.append(r.latency_s)
 
     def _gather_oldest(self) -> None:
-        op, batch, t0, fin = self.inflight.popleft()
-        res = fin()
-        now = self.clock()
-        self._observe_s(op, len(batch), now - t0)
-        for i, r in enumerate(batch):
-            r.result = int(res[i])
-            self._finish_read(r, now)
+        op, batch, t0, fin, bno = self.inflight.popleft()
+        with span("fe.gather", batch=bno):
+            res = fin()
+            now = self.clock()
+            self._observe_s(op, len(batch), now - t0)
+            with span("fe.resolve", batch=bno):
+                for i, r in enumerate(batch):
+                    r.result = int(res[i])
+                    self._finish_read(r, now)
 
     # --------------------------------------------------------- main loop
     def step(self, drain: bool = False) -> bool:
@@ -387,18 +415,23 @@ class FrontEnd:
 
     def latency_percentiles(self, which: str = "served") -> Dict[str, float]:
         """p50/p99/p999/max (ns) over ``served`` (every request that got
-        a result, late or not) or ``ontime`` (goodput) latencies."""
+        a result, late or not) or ``ontime`` (goodput) latencies.  They
+        cover the newest ``LATENCY_WINDOW`` (65,536) such requests; ``n``
+        says how many that is."""
         lat = self._served_lat if which == "served" else self._ontime_lat
         if not lat:
             return {"p50_ns": 0.0, "p99_ns": 0.0, "p999_ns": 0.0,
-                    "max_ns": 0.0}
-        a = np.asarray(lat) * 1e9
+                    "max_ns": 0.0, "n": 0}
+        a = np.fromiter(lat, np.float64, len(lat)) * 1e9
         return {"p50_ns": float(np.percentile(a, 50)),
                 "p99_ns": float(np.percentile(a, 99)),
                 "p999_ns": float(np.percentile(a, 99.9)),
-                "max_ns": float(a.max())}
+                "max_ns": float(a.max()), "n": len(lat)}
 
     def stats(self) -> Dict[str, Any]:
+        """The counters, shed/expire reasons, the service model and the
+        latency percentiles (over the newest ``LATENCY_WINDOW`` requests
+        of each kind; their ``n`` says how many)."""
         c = dict(self.counters)
         c["pending"] = (c["admitted"] - c["completed"] - c["shed"]
                         - c["expired"])
