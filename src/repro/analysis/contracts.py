@@ -67,7 +67,10 @@ ENTRY_POINTS: Tuple[EntryPoint, ...] = (
     EntryPoint(
         name="range_scan",
         module="repro.kernels.range_scan", attr="fused_range_scan_pallas",
-        # the merge loop runs scan_cap (=128 default) trips per query
+        # the rank merge's loops are the endpoint binary searches
+        # (*_iters rounds) and, for a tile whose compares outgrow the
+        # merge budget, one trip per lane chunk; nothing loops over
+        # scan_cap
         trip_budget=256),
     EntryPoint(
         name="shard_router",

@@ -12,14 +12,25 @@ end to end:
    the sorted leaf level the tree's precise placement defines, packed
    once per build/fold swap into a persistent device buffer), the
    compacted run, and the active delta;
-3. **tier-merged emission** — a three-way ordered merge by positioning
-   key walks the three segments in lockstep for ``scan_cap`` steps,
-   emitting payloads into fixed output lanes.  Per candidate, the two
-   newer tiers are probed by exact 64-bit identity (the shared
-   ``probe_pool``), so a superseded copy (re-insert, update, placement
-   shadow) is dropped in favor of its newest version and a TOMBSTONE
-   (-2) in any tier masks every older copy — deletes are range-invisible
-   without any host round trip.
+3. **rank merge** — the three segments merge in one vectorised pass,
+   in (pk, newest tier, in-tier index) order: the first ``scan_cap``
+   candidates of that order are *examined*.  Only the first ``scan_cap``
+   entries of a segment can be, so each tier gives one row of pool
+   blocks per query (a block gather, one index per 128 entries).  A
+   candidate's merged rank is its in-tier offset plus, per other tier,
+   the segment entries before it (``pk <=`` its key for a newer tier,
+   ``pk <`` for an older one), counted by a compare fused into a row
+   reduction.  Each candidate of an older tier is checked once against
+   every newer tier by exact 64-bit identity, inside the same window
+   around its lower bound that the point path's ``probe_pool`` scans,
+   so a superseded copy (re-insert, update, placement shadow) is
+   dropped in favor of its newest version and a TOMBSTONE (-2) in any
+   tier masks every older copy — deletes are range-invisible without
+   any host round trip.  A surviving examined candidate's output lane
+   is the number of live candidates merged before it.  No step loops
+   over ``scan_cap``; batches whose compares outgrow ``_MERGE_BUDGET``
+   run as a loop over lane chunks, so memory stays linear in the batch
+   (the compares grow with ``scan_cap``^2 per lane).
 
 Range semantics are over the **positioning-key order** — the index's
 native sort order.  Without a flow that is the key order itself (the f32
@@ -47,6 +58,7 @@ compiled TPU backend ``xla_range_scan`` runs the SAME body
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -60,9 +72,13 @@ from repro.kernels.fused_lookup import (
     empty_tiers,
     lower_bound,
     positioning_keys,
-    probe_pool,
     select_tile,
 )
+
+# elements that the largest compare of one rank-merge chunk may hold: a
+# 64-lane serve batch at scan_cap 128 is one chunk; larger batches and
+# caps loop over lane chunks, in memory linear in the batch
+_MERGE_BUDGET = 1 << 24
 
 __all__ = ["fused_range_scan_pallas", "xla_range_scan", "scan_merge",
            "ScanPool", "ScanPack"]
@@ -94,6 +110,141 @@ class ScanPack(NamedTuple):
         return self.pool.nbytes()
 
 
+def _pool_rows(pool, lo, size: int):
+    """Per lane, the pool entries ``[lo, lo + size)`` (clamped into the
+    pool) as rows of whole aligned blocks of each array of ``pool``, and
+    each row entry's pool index: a block gather, one index per block."""
+    nmax = pool[0].shape[0]
+    blk = math.gcd(nmax, 128)
+    nblk = min(-(-size // blk) + 1, nmax // blk)
+    first = jnp.clip(lo // blk, 0, nmax // blk - nblk)
+    ids = first[:, None] + jax.lax.broadcasted_iota(
+        jnp.int32, (lo.shape[0], nblk), 1)
+    rows = [x.reshape(-1, blk)[ids].reshape(lo.shape[0], nblk * blk)
+            for x in pool]
+    pos = (first * blk)[:, None] + jax.lax.broadcasted_iota(
+        jnp.int32, (lo.shape[0], nblk * blk), 1)
+    return rows, pos
+
+
+def _row_width(size: int) -> int:
+    """Upper bound on the width of ``_pool_rows``' rows for ``size``."""
+    return (-(-size // 128) + 1) * 128
+
+
+class _Segment(NamedTuple):
+    """One tier's examinable candidates for a batch: rows of its pool
+    holding the first ``scan_cap`` entries of ``[a, b)`` (``real``), with
+    each row entry's in-tier offset ``j``."""
+
+    pk: jnp.ndarray
+    hi: jnp.ndarray
+    lo: jnp.ndarray
+    pv: jnp.ndarray
+    j: jnp.ndarray     # i32[B, W]  pool index - a
+    real: jnp.ndarray  # bool[B, W]
+    n: jnp.ndarray     # i32[B]  real entries
+
+
+def _segment(pool, a, b, scan_cap: int) -> _Segment:
+    (pk, hi, lo, pv), pos = _pool_rows(pool, a, scan_cap)
+    j = pos - a[:, None]
+    n = jnp.clip(b - a, 0, scan_cap)
+    real = (j >= 0) & (j < n[:, None])
+    return _Segment(pk, hi, lo, pv, j, real, n)
+
+
+def _before(seg: _Segment, mask, pk, *, inclusive: bool):
+    """Per candidate key ``pk[B, M]``, how many ``mask``ed entries of
+    ``seg`` hold a smaller key (``<=`` when ``inclusive``): a compare
+    that XLA fuses into its row reduction."""
+    e = seg.pk[:, None, :]
+    x = pk[:, :, None]
+    lt = (e <= x) if inclusive else (e < x)
+    return jnp.sum(lt & mask[:, None, :], axis=2, dtype=jnp.int32)
+
+
+def _held_by(ids, a, plen, window: int, newer: _Segment, cands,
+             scan_cap: int):
+    """Whether the newer tier (identity arrays ``ids``) holds each
+    candidate's identity, for the candidates of the segments ``cands``,
+    exactly as ``probe_pool`` finds it: a match in ``[lb - window,
+    lb + 3*window)`` around the candidate's lower bound ``lb`` in that
+    tier.  For every candidate the merge examines, ``lb`` is ``a`` plus
+    the ``newer`` segment's entries below its key, so the probe rows lie
+    in ``[a - window, a + scan_cap + 3*window)``: dense compares."""
+    (hi, lo), pos = _pool_rows(ids, a - window, scan_cap + 4 * window)
+    ck, chi, clo = (jnp.concatenate([getattr(c, f) for c in cands], axis=1)
+                    for f in ("pk", "hi", "lo"))
+    lb = a[:, None] + _before(newer, newer.real, ck, inclusive=False)
+    p = pos[:, None, :]
+    near = ((p >= (lb - window)[:, :, None])
+            & (p < (lb + 3 * window)[:, :, None]) & (p < plen))
+    same = ((hi[:, None, :] == chi[:, :, None])
+            & (lo[:, None, :] == clo[:, :, None]))
+    return jnp.any(near & same, axis=2)
+
+
+def _rank_merge(bounds, *, spool: ScanPool, tiers: TierPools, scan_cap: int,
+                probe_tiers: bool, run_window: int, delta_window: int):
+    """Steps (3)-(4) of ``scan_merge`` for the lanes whose ``[a, b)``
+    bounds in the scan pool, run and delta are ``bounds``: ``(payloads
+    i32[R, scan_cap], counts i32[R])``."""
+    s0, s1, r0, r1, d0, d1 = bounds
+    t = tiers
+
+    # ---- (3) rank merge.  Merged order is (pk, newest tier, in-tier
+    # index); only the first scan_cap entries of a tier's [a, b) can be
+    # among the first scan_cap merged candidates (the examined ones).  A
+    # candidate's merged rank is its in-tier offset plus, per other
+    # tier, that tier's entries merged before it.
+    segs = [_segment(spool[:4], s0, s1, scan_cap)]
+    if probe_tiers:
+        delta = (t.dl_pk, t.dl_hi, t.dl_lo, t.dl_pv)
+        run = (t.run_pk, t.run_hi, t.run_lo, t.run_pv)
+        segs = [_segment(delta, d0, d1, scan_cap),
+                _segment(run, r0, r1, scan_cap)] + segs
+    held = [jnp.zeros(seg.real.shape, jnp.bool_) for seg in segs]
+    if probe_tiers:
+        # identity probes into the newer tiers — the point path's
+        # matching rule, so a placement shadow whose stored key drifted
+        # 1 ulp from the scan pool's copy still supersedes it (identity
+        # is the matcher, the key only the locator)
+        dl, rn, sp = segs
+        in_dl = _held_by(delta[1:3], d0, t.dl_len[0], delta_window, dl,
+                         (rn, sp), scan_cap)
+        in_rn = _held_by(run[1:3], r0, t.run_len[0], run_window, rn, (sp,),
+                         scan_cap)
+        w = rn.pk.shape[1]
+        held = [held[0], in_dl[:, :w], in_dl[:, w:] | in_rn]
+    live = [seg.real & ~h & (seg.pv != TOMBSTONE)
+            for seg, h in zip(segs, held)]
+
+    # ---- (4) compaction: a valid candidate's output lane is the number
+    # of live candidates merged before it
+    lanes, valid = [], []
+    for i, seg in enumerate(segs):
+        rank = seg.j
+        lane = jnp.cumsum(live[i], axis=1, dtype=jnp.int32) - live[i]
+        for u, other in enumerate(segs):
+            if u != i:
+                # a newer tier's equal keys merge first, an older one's after
+                rank = rank + _before(other, other.real, seg.pk,
+                                      inclusive=u < i)
+                lane = lane + _before(other, live[u], seg.pk,
+                                      inclusive=u < i)
+        lanes.append(lane)
+        valid.append(live[i] & (rank < scan_cap))
+    lane = jnp.concatenate(lanes, axis=1)
+    valid = jnp.concatenate(valid, axis=1)
+    pay = jnp.concatenate([seg.pv for seg in segs], axis=1)
+    cnt = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, scan_cap, 1), 1)
+    hit = valid[:, None, :] & (lane[:, None, :] == col)
+    out = jnp.sum(jnp.where(hit, pay[:, None, :], 0), axis=2)
+    return jnp.where(col[:, :, 0] < cnt[:, None], out, -1), cnt
+
+
 def scan_merge(zlo, zhi, spool: ScanPool, tiers: TierPools, *,
                scan_cap: int, scan_iters: int, probe_tiers: bool,
                run_iters: int, run_window: int, delta_iters: int,
@@ -107,100 +258,52 @@ def scan_merge(zlo, zhi, spool: ScanPool, tiers: TierPools, *,
     candidate (the host oracle); any change here must keep the parity
     tests bit-exact.
     """
-    spk, shi, slo, spv = spool.pk, spool.hi, spool.lo, spool.pv
-    s_len = spool.plen[0]
-    rpk, rhi, rlo, rpv = tiers.run_pk, tiers.run_hi, tiers.run_lo, tiers.run_pv
-    r_len = tiers.run_len[0]
-    dpk, dhi, dlo, dpv = tiers.dl_pk, tiers.dl_hi, tiers.dl_lo, tiers.dl_pv
-    d_len = tiers.dl_len[0]
-    smax = spk.shape[0]
-    rmax = rpk.shape[0]
-    dmax = dpk.shape[0]
-
     # ---- (2) lower-bound both endpoints in every pool: [a, b) holds
     # exactly the pool entries with pk in [zlo, zhi) (searchsorted-left
     # on both ends; an inverted/empty range yields b <= a)
-    s0 = lower_bound(spk, s_len, zlo, scan_iters)
-    s1 = lower_bound(spk, s_len, zhi, scan_iters)
+    ends = jnp.concatenate([zlo, zhi])
+    s0, s1 = jnp.split(
+        lower_bound(spool.pk, spool.plen[0], ends, scan_iters), 2)
     if probe_tiers:
-        r0 = lower_bound(rpk, r_len, zlo, run_iters)
-        r1 = lower_bound(rpk, r_len, zhi, run_iters)
-        d0 = lower_bound(dpk, d_len, zlo, delta_iters)
-        d1 = lower_bound(dpk, d_len, zhi, delta_iters)
+        r0, r1 = jnp.split(lower_bound(tiers.run_pk, tiers.run_len[0], ends,
+                                       run_iters), 2)
+        d0, d1 = jnp.split(lower_bound(tiers.dl_pk, tiers.dl_len[0], ends,
+                                       delta_iters), 2)
     else:
         r0 = r1 = d0 = d1 = jnp.zeros(zlo.shape, jnp.int32)
     total = (jnp.maximum(s1 - s0, 0) + jnp.maximum(r1 - r0, 0)
              + jnp.maximum(d1 - d0, 0))
 
-    # ---- (3) three-way ordered merge, scan_cap lockstep rounds.  Each
-    # round picks the per-lane minimum head key (ties prefer the newest
-    # tier: delta > run > scan pool), probes the newer tiers for a
-    # superseding copy of the candidate's identity, and compacts valid
-    # payloads into the output lanes via a one-hot column write.
-    col = jax.lax.broadcasted_iota(jnp.int32, (zlo.shape[0], scan_cap), 1)
+    # ---- (3)-(4) rank merge, over lane chunks whose largest compare
+    # fits _MERGE_BUDGET
+    bounds = (s0, s1, r0, r1, d0, d1)
+    merge = functools.partial(
+        _rank_merge, spool=spool, tiers=tiers, scan_cap=scan_cap,
+        probe_tiers=probe_tiers, run_window=run_window,
+        delta_window=delta_window)
+    b = zlo.shape[0]
+    # per lane: the rank compares [seg, seg], the delta's identity
+    # check [2 seg, probe] and the output's one-hot [scan_cap, 3 seg]
+    seg = _row_width(scan_cap)
+    per_row = max(seg, scan_cap * (3 if probe_tiers else 1)) * seg
+    if probe_tiers:
+        probe = _row_width(scan_cap + 4 * max(run_window, delta_window))
+        per_row = max(per_row, 2 * seg * probe)
+    rows = math.gcd(b, 1 << max(_MERGE_BUDGET // per_row, 1).bit_length() - 1)
+    if rows == b:
+        out, cnt = merge(bounds)
+    else:
+        def chunk(bnd):
+            # a chunk of empty ranges (the batch's padding) merges nothing
+            s0, s1, r0, r1, d0, d1 = bnd
+            some = jnp.any((s1 > s0) | (r1 > r0) | (d1 > d0))
+            return jax.lax.cond(some, merge, lambda _: (
+                jnp.full((rows, scan_cap), -1, jnp.int32),
+                jnp.zeros((rows,), jnp.int32)), bnd)
 
-    def merge_step(_, carry):
-        it, ir, idl, cnt, out = carry
-        t_ok = it < s1
-        r_ok = ir < r1
-        d_ok = idl < d1
-        ti = jnp.clip(it, 0, smax - 1)
-        ri = jnp.clip(ir, 0, rmax - 1)
-        di = jnp.clip(idl, 0, dmax - 1)
-        t_pk = jnp.where(t_ok, spk[ti], jnp.inf)
-        r_pk = jnp.where(r_ok, rpk[ri], jnp.inf)
-        d_pk = jnp.where(d_ok, dpk[di], jnp.inf)
-        m = jnp.minimum(t_pk, jnp.minimum(r_pk, d_pk))
-        any_c = m < jnp.inf
-        pick_d = any_c & (d_pk == m)
-        pick_r = any_c & ~pick_d & (r_pk == m)
-        pick_t = any_c & ~pick_d & ~pick_r
-
-        chi = jnp.where(pick_d, dhi[di], jnp.where(pick_r, rhi[ri], shi[ti]))
-        clo = jnp.where(pick_d, dlo[di], jnp.where(pick_r, rlo[ri], slo[ti]))
-        cpv = jnp.where(pick_d, dpv[di], jnp.where(pick_r, rpv[ri], spv[ti]))
-
-        if probe_tiers:
-            # per-candidate identity probe into the newer tiers — the
-            # point path's exact machinery, so a placement shadow whose
-            # stored key drifted 1 ulp from the scan pool's copy still
-            # supersedes it (identity is the matcher, the key only the
-            # locator).  Length-gated like the point kernel's tier_stage.
-            miss = jnp.full(m.shape, -1, jnp.int32)
-
-            def probe_delta(_):
-                lb = lower_bound(dpk, d_len, m, delta_iters)
-                return probe_pool(dhi, dlo, dpv, d_len, lb, dmax,
-                                  delta_window, chi, clo)
-
-            def probe_run(_):
-                lb = lower_bound(rpk, r_len, m, run_iters)
-                return probe_pool(rhi, rlo, rpv, r_len, lb, rmax,
-                                  run_window, chi, clo)
-
-            dl_pay = jax.lax.cond(d_len > 0, probe_delta,
-                                  lambda _: miss, None)
-            rn_pay = jax.lax.cond(r_len > 0, probe_run,
-                                  lambda _: miss, None)
-            superseded = ((pick_t & ((dl_pay != -1) | (rn_pay != -1)))
-                          | (pick_r & (dl_pay != -1)))
-        else:
-            superseded = jnp.zeros(m.shape, jnp.bool_)
-
-        valid = any_c & ~superseded & (cpv != TOMBSTONE)
-        out = jnp.where((col == cnt[:, None]) & valid[:, None],
-                        cpv[:, None], out)
-        cnt = cnt + valid.astype(jnp.int32)
-        it = it + pick_t.astype(jnp.int32)
-        ir = ir + pick_r.astype(jnp.int32)
-        idl = idl + pick_d.astype(jnp.int32)
-        return it, ir, idl, cnt, out
-
-    zero = jnp.zeros(zlo.shape, jnp.int32)
-    out0 = jnp.full((zlo.shape[0], scan_cap), -1, jnp.int32)
-    _, _, _, cnt, out = jax.lax.fori_loop(
-        0, scan_cap, merge_step, (s0, r0, d0, zero, out0))
-
+        out, cnt = jax.lax.map(chunk, tuple(x.reshape(-1, rows)
+                                            for x in bounds))
+        out, cnt = out.reshape(b, scan_cap), cnt.reshape(b)
     return out, cnt, total
 
 

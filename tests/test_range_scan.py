@@ -362,3 +362,190 @@ def test_afli_delete_batch_vectorized_semantics():
     assert ok[:40].all() and not ok[40:].any()
     assert (nfl.lookup_batch(keys[:40]) == -1).all()
     assert not nfl.delete_batch(keys[:40]).any()
+
+
+def _tiered(static, run=(), delta=(), delete=()):
+    """FlatAFLI with ``static`` built into the tree and scan pool, ``run``
+    (key, payload) pairs retired into the compacted run, then ``delta``
+    pairs and ``delete`` tombstones left in the active delta; no fold."""
+    idx = FlatAFLI(FlatAFLIConfig(delta_cap=4096, rebuild_frac=4.0))
+    static = np.asarray(static, np.float64)
+    idx.build(static, np.arange(static.shape[0], dtype=np.int64))
+    for pairs in (run, delta):
+        if len(pairs):
+            k, v = zip(*pairs)
+            idx.insert_batch(np.array(k, np.float64), np.array(v))
+        if pairs is run:
+            idx._merge_delta_into_run()
+    if len(delete):
+        assert idx.delete_batch(np.asarray(delete, np.float64)).all()
+    assert idx._fold is None
+    return idx
+
+
+_BASE = 2.0 ** 30   # f32 ulp 128: base + 256*g + i (|i| < 64) collide
+
+
+def _scan_case(case):
+    """(index, lo keys, hi keys, cap) for one tie/truncation edge."""
+    if case == "equal-keys":
+        # every group's identities share one positioning key and are
+        # spread over all three tiers; some identities re-inserted
+        # across tiers, some deleted
+        keys = _BASE + 256.0 * np.arange(12)[:, None] + np.arange(18)
+        tier = np.arange(18) % 3
+        idx = _tiered(keys[:, tier == 0].ravel(),
+                      run=[(k, 10_000 + i) for i, k in
+                           enumerate(np.concatenate(
+                               [keys[:, tier == 1].ravel(),
+                                keys[::2, 0]]))],
+                      delta=[(k, 20_000 + i) for i, k in
+                             enumerate(np.concatenate(
+                                 [keys[:, tier == 2].ravel(),
+                                  keys[1::3, 1], keys[::4, 3]]))],
+                      delete=keys[::5, 6])
+        lo = _BASE + 256.0 * np.array([0, 2, 5, 0, 11]) - 128
+        hi = _BASE + 256.0 * np.array([1, 5, 6, 12, 12]) + 128
+        return idx, lo, hi, 512
+    if case == "tombstone":
+        # a delta tombstone masks the run's copy and the pool's copy
+        keys = np.arange(1, 401, dtype=np.float64) * 1000.0
+        idx = _tiered(keys, run=[(k, 50_000 + i) for i, k in
+                                 enumerate(keys[10:300:3])],
+                      delete=np.union1d(keys[10:300:6], keys[11:300:7]))
+        lo, hi = keys[[0, 5, 100, 250]], keys[[60, 120, 320, 399]]
+        return idx, lo, hi, 512
+    if case == "reinsert":
+        # re-inserts supersede the pool's copy (from the run and the
+        # delta) and the run's copy (from the delta)
+        keys = np.arange(1, 301, dtype=np.float64) * 1000.0
+        idx = _tiered(keys, run=[(k, 60_000 + i) for i, k in
+                                 enumerate(keys[::4])],
+                      delta=[(k, 70_000 + i) for i, k in
+                             enumerate(np.concatenate([keys[::8],
+                                                       keys[1::5]]))])
+        lo, hi = keys[[0, 40, 150]], keys[[90, 130, 299]]
+        return idx, lo, hi, 512
+    if case == "truncation-mixed":
+        # interleaved tiers with ties, superseded copies and tombstones:
+        # the cap falls among candidates of all three tiers
+        keys = np.arange(1, 601, dtype=np.float64) * 1000.0
+        ties = _BASE + np.arange(30)
+        idx = _tiered(np.concatenate([keys[0::3], ties[0::3]]),
+                      run=[(k, 80_000 + i) for i, k in
+                           enumerate(np.concatenate(
+                               [keys[1::3], ties[1::3], keys[0:90:9]]))],
+                      delta=[(k, 90_000 + i) for i, k in
+                             enumerate(np.concatenate(
+                                 [keys[2::3], ties[2::3], keys[1:90:7]]))],
+                      delete=keys[3:90:11])
+        lo = np.array([keys[0], keys[17], keys[40], _BASE - 128])
+        hi = np.array([keys[80], keys[70], keys[500], _BASE + 128])
+        return idx, lo, hi, 16
+    if case == "wide-cap":
+        # a scan_cap of over a thousand, many lane chunks' worth of
+        # compares, with the same ties, superseded copies, tombstones
+        # and truncation
+        keys = np.arange(1, 3001, dtype=np.float64) * 1000.0
+        ties = _BASE + np.arange(60)
+        idx = _tiered(np.concatenate([keys[0::3], ties[0::3]]),
+                      run=[(k, 80_000 + i) for i, k in
+                           enumerate(np.concatenate(
+                               [keys[1::3], ties[1::3], keys[0:900:9]]))],
+                      delta=[(k, 90_000 + i) for i, k in
+                             enumerate(np.concatenate(
+                                 [keys[2::3], ties[2::3], keys[1:900:7]]))],
+                      delete=keys[3:900:11])
+        lo = np.array([keys[0], keys[17], keys[400], _BASE - 128])
+        hi = np.array([keys[1500], keys[900], keys[2999], _BASE + 128])
+        return idx, lo, hi, 1100
+    if case == "one-tier-over-cap":
+        # the run alone, then the pool alone, holds more than scan_cap
+        keys = np.arange(1, 1001, dtype=np.float64) * 1000.0
+        run = keys[200:500] + 500.0
+        idx = _tiered(keys, run=[(k, 40_000 + i) for i, k in
+                                 enumerate(run)],
+                      delta=[(k, 30_000 + i) for i, k in
+                             enumerate(keys[600:604] + 250.0)])
+        lo = np.array([run[0], keys[600], keys[100], run[50] - 1.0])
+        hi = np.array([run[200], keys[900], keys[210], run[52]])
+        return idx, lo, hi, 32
+    assert case == "empty-and-padding"
+    # inverted, empty and gap ranges, and lanes equal to the batch's own
+    # zero padding (lo == hi == 0), in a batch padded to a power of two
+    keys = np.arange(1, 201, dtype=np.float64) * 1000.0
+    idx = _tiered(keys, run=[(k, 1_000 + i) for i, k in
+                             enumerate(keys[::3] + 500.0)],
+                  delta=[(k, 2_000 + i) for i, k in
+                         enumerate(keys[1::4] + 250.0)],
+                  delete=keys[::7])
+    lo = np.array([keys[90], keys[20], keys[5] + 600.0, 0.0, 0.0])
+    hi = np.array([keys[10], keys[20], keys[6] - 100.0, 0.0, keys[199]])
+    return idx, lo, hi, 128
+
+
+@pytest.mark.parametrize("case", ["equal-keys", "tombstone", "reinsert",
+                                  "truncation-mixed", "one-tier-over-cap",
+                                  "empty-and-padding", "wide-cap"])
+@pytest.mark.parametrize("route", ["fused", "xla"])
+def test_scan_routes_match_host_oracle_at_edges(route, case, monkeypatch):
+    """Both lowerings of ``scan_merge`` (the interpret-mode Pallas kernel
+    and the XLA range route) against ``_range_scan_host``, bit for bit in
+    payloads, counts and totals, at the merge's tie and truncation
+    edges: ties across tiers break newest tier first, then by index, and
+    truncation keeps the first ``cap`` candidates of that order."""
+    from repro.kernels import ops
+
+    idx, lo, hi, cap = _scan_case(case)
+    assert idx._run_pk.shape[0] and idx._delta_pk.shape[0]
+    if route == "xla":
+        monkeypatch.setattr(ops, "traversal_route", lambda interpret: "xla")
+    got = idx.scan_batch(lo, hi, cap=cap)
+    assert idx.last_scan_dispatch["path"] == route
+    assert idx.last_scan_dispatch["tier_path"] != "none"
+    want = idx._range_scan_host(_z32(lo), _z32(hi), cap)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    _, cnt, tot = got
+    if case in ("truncation-mixed", "one-tier-over-cap", "wide-cap"):
+        assert (tot > cap).sum() >= 2
+    elif case == "empty-and-padding":
+        assert (cnt[:4] == 0).all() and (tot[:4] == 0).all() and cnt[4] > 0
+    else:
+        assert (tot <= cap).all() and (cnt > 0).all()
+
+
+def test_range_route_has_no_scan_cap_loop():
+    """The rank merge has no loop over ``scan_cap``: tracing the XLA
+    range route at ``scan_cap=128`` with live tiers for a 64-lane batch
+    finds no ``while`` and no ``scan`` of ``scan_cap`` or more trips (the
+    only loops are the endpoint binary searches, ``*_iters`` rounds)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis.jaxpr_checks import walk_jaxpr
+    from repro.kernels.fused_lookup import TierPools
+    from repro.kernels.range_scan import ScanPool, xla_range_scan
+
+    def pool(n):
+        return (jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.uint32),
+                jnp.zeros((n,), jnp.uint32), jnp.zeros((n,), jnp.int32),
+                jnp.zeros((128,), jnp.int32))
+
+    feats = jnp.zeros((64, 1), jnp.float32)
+
+    def route(flo, fhi, sp, tiers):
+        return xla_range_scan(flo, fhi, jnp.zeros((1, 1), jnp.float32),
+                              sp, tiers, dim=1, scan_cap=128,
+                              scan_iters=24, use_flow=False,
+                              probe_tiers=True, run_iters=22, run_window=16,
+                              delta_iters=17, delta_window=4)
+
+    closed = jax.make_jaxpr(route)(feats, feats, ScanPool(*pool(1 << 12)),
+                                   TierPools(*pool(1 << 10), *pool(1 << 10)))
+    loops = []
+    walk_jaxpr(closed.jaxpr, lambda eqn, _: loops.append(
+        (eqn.primitive.name, eqn.params.get("length")))
+        if eqn.primitive.name in ("scan", "while") else None)
+    assert loops and all(p == "scan" for p, _ in loops)
+    assert max(n for _, n in loops) < 128, loops
