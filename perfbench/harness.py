@@ -12,8 +12,9 @@ later change adds a configuration, a mix or a metric as new files:
   ``Run``; the harness asks each metric that the cell reports.
 
 A run: check the device, build the index from ``--seed`` (set-up),
-warm every shape the mix uses, drive the front end for ``--seconds``
-(the window), drain, read the metrics, free the index, and only then
+pre-fill the write tiers where the mix asks for it, warm every shape the
+mix uses, drive the front end for ``--seconds`` (the window) in the
+mix's closed or open loop, drain, read the metrics, free the index, and only then
 replay the window's batches through the plain reference
 (``reference.py``) to decide ``correct``.  With ``--trace 1`` a few
 seconds of the window are profiled and the per-layer metrics are
@@ -36,7 +37,7 @@ import time
 import numpy as np
 
 from perfbench.reference import Batch, Reference, Verdict, record
-from perfbench.traffic.gen import RequestStream, load_mix
+from perfbench.traffic.gen import RequestStream, arrival_times, load_mix
 from perfbench.traffic.keys import make_keys, split_half
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -144,12 +145,21 @@ class CompileCounter:
 class SpannedIndex:
     """The index as the front end sees it, with a host span around each
     call into it while a trace is being taken, and a record of what the
-    traced calls asked for (the per-layer readers divide by it)."""
+    traced calls asked for (the per-layer readers divide by it).
+
+    ``insert_calls``, where a list, gets the clock and the length of
+    each insert call; with ``watch_fold`` set, ``fold_started`` becomes
+    the clock of the first insert call after which a fold is in
+    flight."""
 
     def __init__(self, index):
         self.index = index
         self.tracing = False
-        self.traced = {"point_keys": [], "range_lo": [], "range_hi": []}
+        self.traced = {"point_keys": [], "range_lo": [], "range_hi": [],
+                       "insert_calls": 0}
+        self.insert_calls = None
+        self.watch_fold = False
+        self.fold_started = None
 
     def _span(self, name):
         return span(self.tracing, name)
@@ -174,26 +184,37 @@ class SpannedIndex:
             return self.index.scan_batch(lo, hi)
 
     def insert_batch(self, keys, payloads):
+        if self.tracing:
+            self.traced["insert_calls"] += 1
+        t = time.perf_counter()
         with self._span("index.insert"):
-            return self.index.insert_batch(keys, payloads)
+            out = self.index.insert_batch(keys, payloads)
+        if self.insert_calls is not None:
+            self.insert_calls.append((t, time.perf_counter() - t))
+        if self.watch_fold and write_path(self.index).get("fold_active"):
+            self.fold_started = t
+            self.watch_fold = False
+        return out
 
 
 # --------------------------------------------------------------- driving
 @dataclasses.dataclass
 class Served:
-    """What a stretch of the closed loop served: requests sent, each
-    one's latency and the clock when it came back."""
+    """What a stretch of the loop served: requests sent, each one's
+    latency and the clock when it came back."""
 
     sent: int
     latency_s: np.ndarray
     t_back: np.ndarray
     longest_step: tuple   # (seconds, clock at its end) of the loop's longest pass
+    backlog: int = 0      # requests outstanding when ``until`` came
 
 
 class Driver:
-    """Drives the front end with a cell's mix in a closed loop and keeps
-    the client's clock: each request is timed from its submit to the
-    moment the loop hands its answer back.
+    """Drives the front end with a cell's mix and keeps the client's
+    clock: a closed loop times each request from its submit, an open loop
+    from its scheduled arrival, to the moment the loop hands its answer
+    back.
 
     Every batch the front end dispatches goes into ``log`` in dispatch
     order, as a compact ``reference.Batch`` once it is answered; the
@@ -220,6 +241,17 @@ class Driver:
 
     def _span(self, name):
         return span(self.tracer is not None and self.tracer.tracing, name)
+
+    def _submit(self, live: dict, t_sub: float | None = None) -> None:
+        """The stream's next request, timed from ``t_sub`` (default: the
+        clock as it is submitted)."""
+        op, key, hi, pay = self.stream.next()
+        r = self.Req(self.rid, op, key, hi=hi, payload=pay,
+                     deadline_s=self.deadline)
+        self.rid += 1
+        r.t_sub = self.clock() if t_sub is None else t_sub
+        live[r.rid] = r
+        self.fe.submit(r)
 
     def _harvest(self, now: float, live: dict, lat: list, back: list) -> int:
         """Record the answered batches of ``pending``, stamped ``now``,
@@ -259,31 +291,64 @@ class Driver:
                n_requests: int | None = None) -> Served:
         """Keep ``clients`` requests outstanding until ``until`` (clock)
         or until ``n_requests`` were sent; then drain."""
+        sent = 0
+
+        def feed(live):
+            nonlocal sent
+            while len(live) < clients:
+                self._submit(live)
+                sent += 1
+                if n_requests is not None and sent >= n_requests:
+                    return True
+            return False
+
+        return self._loop(feed, until, lambda: sent)
+
+    def open(self, arrivals: np.ndarray, until: float) -> Served:
+        """Submit request ``i`` once the clock reaches ``arrivals[i]``,
+        whether or not earlier ones were answered, for the arrivals
+        before ``until``; then drain.  Each request is timed from its
+        scheduled arrival, so a pass that blocks the loop counts in the
+        latency of every request due during it.  With nothing to do the
+        loop waits for the next arrival in sleeps of at most 0.1 ms."""
+        n = int(np.searchsorted(arrivals, until, side="left"))
+        i = 0
+
+        def feed(live):
+            nonlocal i
+            now = self.clock()
+            while i < n and arrivals[i] <= now:
+                self._submit(live, float(arrivals[i]))
+                i += 1
+            return i >= n
+
+        def wait(now):
+            if i < n and arrivals[i] > now:
+                time.sleep(min(arrivals[i] - now, 1e-4))
+
+        return self._loop(feed, until, lambda: i, wait)
+
+    def _loop(self, feed, until, n_sent, wait=None) -> Served:
+        """The loop both drivers share: ``feed(live)`` submits what is
+        due and says whether the last request was sent; then one
+        ``fe.step``; answers are harvested; from ``until`` on nothing more
+        is sent, and the loop drains.  ``wait(now)`` is called after a
+        pass in which the front end had nothing to do."""
         fe = self.fe
         live: dict = {}            # rid -> request, outstanding
         lat: list = []
         back: list = []
-        sent = answered = 0
+        answered = 0
+        backlog = None
         resolved0 = self._resolved()
         stop = False
         longest, prev = (0.0, 0.0), self.clock()
         while True:
             if not stop:
                 with self._span("gen"):
-                    while len(live) < clients:
-                        op, key, hi, pay = self.stream.next()
-                        r = self.Req(self.rid, op, key, hi=hi, payload=pay,
-                                     deadline_s=self.deadline)
-                        self.rid += 1
-                        r.t_sub = self.clock()
-                        live[r.rid] = r
-                        fe.submit(r)
-                        sent += 1
-                        if n_requests is not None and sent >= n_requests:
-                            stop = True
-                            break
+                    stop = feed(live)
             with self._span("fe.step"):
-                fe.step(drain=stop)
+                busy = fe.step(drain=stop)
             now = self.clock()
             if now - prev > longest[0]:
                 longest = (now - prev, now)
@@ -291,11 +356,16 @@ class Driver:
             answered += self._harvest(now, live, lat, back)
             if self._resolved() - resolved0 != answered:
                 answered += self._sweep_undispatched(now, live, lat, back)
-            if until is not None and now >= until:
+            if until is not None and now >= until and backlog is None:
                 stop = True
+                backlog = len(live)
             if stop and not live:
-                return Served(sent, np.concatenate(lat or [np.zeros(0)]),
-                              np.concatenate(back or [np.zeros(0)]), longest)
+                return Served(n_sent(), np.concatenate(lat or [np.zeros(0)]),
+                              np.concatenate(back or [np.zeros(0)]), longest,
+                              backlog or 0)
+            if wait is not None and not busy and not stop:
+                with self._span("gen"):
+                    wait(now)
 
 
 # ------------------------------------------------------------------ run
@@ -314,6 +384,7 @@ class Run:
         default_factory=lambda: np.zeros(0))   # one per request sent
     completed_in_window: int = 0
     fe_window: dict = dataclasses.field(default_factory=dict)
+    fe_traced: dict = dataclasses.field(default_factory=dict)  # while traced
     build: dict = dataclasses.field(default_factory=dict)
     use_flow: bool = False
     trace: object = None          # trace.Reduced, with --trace 1
@@ -393,6 +464,47 @@ def warm_up(nfl, cell: Cell, stream: RequestStream, load_keys, log_: list,
             nfl.scan_batch(load_keys[s], load_keys[s + 50])
 
 
+def prefill_sizes(mix: dict) -> list:
+    """The batch sizes of the mix's pre-fill: ``prefill_inserts`` keys in
+    batches of ``prefill_batch``, the last ``prefill_warm_sizes`` (k)
+    batches holding k, k - 1, ..., 1 keys, so that every insert batch
+    size up to k has run before the window."""
+    n = int(mix.get("prefill_inserts", 0))
+    warm = list(range(int(mix.get("prefill_warm_sizes", 0)), 0, -1))
+    rest = n - sum(warm)
+    if rest < 0:
+        raise ValueError("prefill_inserts is smaller than the warm sizes")
+    size = int(mix.get("prefill_batch", rest or 1))
+    return [size] * (rest // size) + ([rest % size] if rest % size else []) \
+        + warm
+
+
+def prefill(nfl, mix: dict, stream: RequestStream, log_: list) -> None:
+    """Insert the mix's pre-fill (``prefill_sizes``) from the unloaded
+    half through the index's public ``insert_batch``, and log each batch
+    for the reference."""
+    sizes = prefill_sizes(mix)
+    if not sizes:
+        return
+    t = time.perf_counter()
+    for size in sizes:
+        k, p = stream.take_inserts(size)
+        nfl.insert_batch(k, p)
+        log_.append(Batch("insert", k, pays=p))
+    log(f"prefill: {sum(sizes)} inserts in {len(sizes)} batches "
+        f"(sizes {sizes[0]}, then {sorted(set(sizes[1:]), reverse=True)}), "
+        f"{time.perf_counter() - t:.2f} s; write path {write_path(nfl)}")
+
+
+def write_path(nfl) -> dict:
+    """The write tiers and the fold as ``FlatAFLI.stats()`` gives them."""
+    idx = getattr(nfl, "index", None)
+    st = idx.stats() if idx is not None and hasattr(idx, "stats") else {}
+    return {k: st[k] for k in ("n_keys", "delta_len", "run_len",
+                                "fold_active", "n_rebuilds", "n_reflows")
+            if k in st}
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              root: str = ROOT, t_start: float | None = None,
              require_chip: bool = True, system=None, out=None,
@@ -428,7 +540,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     # ---- set-up: data from the seed, the index, warm-up
     cfg = cell.config
-    ss_keys, ss_traffic = np.random.SeedSequence(seed).spawn(2)
+    ss_keys, ss_traffic, ss_arrivals = np.random.SeedSequence(seed).spawn(3)
     rng = np.random.default_rng(ss_keys)
     keys = make_keys(cfg["dataset"], int(cfg["n_keys"]), rng)
     load_k, load_p, ins_k, ins_p = split_half(keys, rng)
@@ -444,14 +556,27 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                            np.random.default_rng(ss_traffic))
     setup_log: list = []
     fecfg = FrontEndConfig()
+    prefill(nfl, cell.mix, stream, setup_log)
     warm_up(nfl, cell, stream, load_k, setup_log, fecfg.max_batch)
     spanned = SpannedIndex(nfl)
     fe = FrontEnd(spanned, fecfg)
     drv = Driver(fe, stream, cell.mix, clock, ServiceRequest, spanned)
     drv.log = setup_log
-    clients = int(cell.mix["clients"])
+    is_open = cell.mix["loop"] == "open"
+    clients = int(cell.mix["warmup_clients" if is_open else "clients"])
     drv.closed(clients, n_requests=int(cell.mix["warmup_requests"]))
+    if is_open:
+        # one block of gaps lasts the window: every seed is offered the
+        # same number of requests in it
+        rate = float(cell.mix["rate_per_s"])
+        block = max(round(rate * seconds), 1)
+        arrivals = arrival_times(rate, 2 * block,
+                                 np.random.default_rng(ss_arrivals), block)
+    write0 = write_path(nfl)
+    spanned.watch_fold = bool(cell.mix.get("prefill_inserts")
+                              and not write0.get("fold_active"))
     statics0 = _serving_statics(nfl)
+    spanned.insert_calls = []
     fe0 = dict(fe.counters)
     compiles0, hits0 = counter.compiles, counter.cache_hits
 
@@ -466,9 +591,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = clock()
     run.setup_s = t0 - t_start
     if trace:
-        t_tr0 = t0 + min(1.0, seconds / 4)
-        t_tr1 = t_tr0 + min(3.0, seconds / 2)
-        trace_window = _TraceWindow(jax, tmp, spanned, t_tr0, t_tr1, clock)
+        trace_window = _TraceWindow(jax, tmp, spanned, fe,
+                                    t0 + min(1.0, seconds / 4),
+                                    min(3.0, seconds / 2), clock)
         fe_step = fe.step
 
         def step(drain: bool = False):
@@ -476,13 +601,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             return fe_step(drain)
 
         fe.step = step
-    served = drv.closed(clients, until=t0 + seconds)
+    if is_open:
+        served = drv.open(t0 + arrivals, until=t0 + seconds)
+    else:
+        served = drv.closed(clients, until=t0 + seconds)
     t_end = t0 + seconds
     gc_log.close()
     jax.config.update("jax_log_compiles", False)
     if trace:
         trace_window.finish()
         fe.step = fe_step
+        run.fe_traced = trace_window.fe_counts()
     run.window_s = seconds
     run.attempted = served.sent
     run.latency_s = served.latency_s
@@ -497,6 +626,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     log(f"longest loop pass: {served.longest_step[0] * 1e3:.1f} ms, ending "
         f"{served.longest_step[1] - t0:.2f} s into the window; "
         f"{gc_log.summary(t0)}")
+    log(f"write path at the window's start {json.dumps(write0)}, at its "
+        f"end {json.dumps(write_path(nfl))}; "
+        + ("a fold was in flight at the start" if write0.get("fold_active")
+           else "no fold started in the window"
+           if spanned.fold_started is None else
+           f"the fold started {spanned.fold_started - t0:.3f} s into the "
+           "window"))
+    log(_insert_calls_summary(spanned.insert_calls, t0))
+    if is_open:
+        log(f"open loop: offered {rate} /s, sent {served.sent}, backlog at "
+            f"the window's end {served.backlog} "
+            f"({served.backlog / rate:.3f} s of arrivals)")
     statics1 = _serving_statics(nfl)
     if statics1 != statics0:
         log(f"serving statics moved in the window: {statics0} -> "
@@ -578,6 +719,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     return 0
 
 
+def _insert_calls_summary(calls: list, t0: float) -> str:
+    """The insert calls of the window and its drain: count, median,
+    90th percentile and longest, and the five longest with their start
+    (seconds into the window)."""
+    if not calls:
+        return "insert calls: none"
+    ms = np.array([d for _, d in calls]) * 1e3
+    top = sorted(calls, key=lambda c: -c[1])[:5]
+    return (f"insert calls: {len(calls)}, median {np.median(ms):.2f} ms, "
+            f"p90 {np.percentile(ms, 90):.2f} ms, longest "
+            + ", ".join(f"{d * 1e3:.1f} ms at {t - t0:.2f} s"
+                        for t, d in top))
+
+
 def check(log_: list, load_k, load_p, scan_cap: int,
           use_flow: bool) -> Verdict:
     """Replay every batch sent to the index, in dispatch order, through
@@ -635,29 +790,48 @@ class _GcLog:
 
 
 class _TraceWindow:
-    """Starts the profiler at ``t_start`` and stops it at ``t_stop``
-    (polled from the loop), switching the benchmark's spans on inside."""
+    """Starts the profiler at the first poll from ``t_start`` on and
+    stops it ``length`` seconds after it started (polled from the loop),
+    switching the benchmark's spans on inside.  Where the run awaits a
+    fold (``SpannedIndex.watch_fold``), the start also waits for the
+    insert call that starts it, up to ``t_start + length``, so the trace
+    covers the fold's ticks and not, in some seeds, its start.  The
+    front end's counters are read as the trace starts and just before it
+    is stopped, so the stop's stall is not counted."""
 
-    def __init__(self, jax, path, spanned, t_start, t_stop, clock):
+    def __init__(self, jax, path, spanned, fe, t_start, length, clock):
         self.jax, self.path, self.spanned = jax, path, spanned
-        self.t_start, self.t_stop, self.clock = t_start, t_stop, clock
+        self.fe = fe
+        self.t_start, self.length, self.clock = t_start, length, clock
+        self.t_stop = None
         self.state = 0
         self.stop_s = 0.0
+        self.fe0: dict = {}
+        self.fe1: dict = {}
+
+    def fe_counts(self) -> dict:
+        """The front end's counters over the traced stretch."""
+        return {k: self.fe1[k] - self.fe0[k] for k in self.fe1}
 
     def poll(self) -> None:
         now = self.clock()
-        if self.state == 0 and now >= self.t_start:
+        if self.state == 0 and now >= self.t_start and (
+                not self.spanned.watch_fold
+                or now >= self.t_start + self.length):
             opts = self.jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0   # the benchmark's spans only
             opts.host_tracer_level = 1
             self.jax.profiler.start_trace(self.path, profiler_options=opts)
+            self.fe0 = dict(self.fe.counters)
             self.spanned.tracing = True
+            self.t_stop = now + self.length
             self.state = 1
         elif self.state == 1 and now >= self.t_stop:
             self.stop()
 
     def stop(self) -> None:
         self.spanned.tracing = False
+        self.fe1 = dict(self.fe.counters)
         t = self.clock()
         self.jax.profiler.stop_trace()
         self.stop_s = self.clock() - t

@@ -28,7 +28,6 @@ precision lower, whose answers the comparison has to refuse.
 
 from __future__ import annotations
 
-import bisect
 import itertools
 from collections import Counter
 from typing import NamedTuple
@@ -76,9 +75,11 @@ class Reference:
         # base keys deleted or overwritten since the load
         self.base_changed: set = set()
         self.base_set = set(self.base_k.tolist())
-        # keys written after the load that are not base keys, by z
-        self.extra_z: list = []
-        self.extra_k: list = []
+        # keys written after the load that are not base keys: sorted by
+        # z, and those not yet sorted in (the ranges sort them in)
+        self.extra_z = np.zeros(0, np.float64)
+        self.extra_k = np.zeros(0, np.float64)
+        self.extra_new: list = []
         self.extra_set: set = set()
 
     def _key(self, key) -> float:
@@ -91,10 +92,7 @@ class Reference:
             self.base_changed.add(k)
         elif k not in self.extra_set:
             self.extra_set.add(k)
-            z = float(np.float32(k))
-            i = bisect.bisect_right(self.extra_z, z)
-            self.extra_z.insert(i, z)
-            self.extra_k.insert(i, k)
+            self.extra_new.append(k)
         self.pay[k] = int(payload)
         return True
 
@@ -111,8 +109,21 @@ class Reference:
     def point(self, key) -> int:
         return self.pay.get(self._key(key), -1)
 
+    def _sort_extra(self) -> None:
+        """Sort the keys written since the last range into ``extra_z``
+        / ``extra_k`` (by the float32 positioning key)."""
+        k = np.asarray(self.extra_new, np.float64)
+        self.extra_new = []
+        z = k.astype(np.float32).astype(np.float64)
+        order = np.argsort(z, kind="stable")
+        at = np.searchsorted(self.extra_z, z[order], side="right")
+        self.extra_z = np.insert(self.extra_z, at, z[order])
+        self.extra_k = np.insert(self.extra_k, at, k[order])
+
     def ranges(self, lo: np.ndarray, hi: np.ndarray) -> list:
         """Per range, the payloads of the live keys in it."""
+        if self.extra_new:
+            self._sort_extra()
         zlo = np.asarray(lo, np.float64).astype(self.key_dtype).astype(
             np.float32)
         zhi = np.asarray(hi, np.float64).astype(self.key_dtype).astype(
@@ -127,10 +138,11 @@ class Reference:
                        if k in pay]
             else:
                 got = self.base_p[a[i]:b[i]].tolist()
-            if self.extra_z:
-                x = bisect.bisect_left(self.extra_z, float(zlo[i]))
-                y = bisect.bisect_left(self.extra_z, float(zhi[i]))
-                got += [pay[k] for k in self.extra_k[x:y] if k in pay]
+            if self.extra_z.shape[0]:
+                x = int(np.searchsorted(self.extra_z, zlo[i], side="left"))
+                y = int(np.searchsorted(self.extra_z, zhi[i], side="left"))
+                got += [pay[k] for k in self.extra_k[x:y].tolist()
+                        if k in pay]
             out.append(got)
         return out
 

@@ -121,3 +121,31 @@ def test_recorded_trace():
         {"fe.step": 0.018061612, "index.lookup_async": 0.000455523})
     assert (sum(r.idle_by_span.values())
             == pytest.approx(r.window_s - r.busy_s))
+
+
+def test_insert_ms_per_call_reads_the_insert_span_over_traced_calls():
+    """Two ``index.insert`` spans of 2 and 3 ms, overlapping by 1 ms:
+    4 ms of union over two traced calls is 2 ms a call."""
+    from perfbench.harness import load_reader
+
+    ms = 1_000_000
+    dev = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=[ev("%a.1 = f32[8] fusion()", 0, ms)])])
+    host = NS(name="/host:CPU", lines=[NS(name="spans", events=[
+        ev("fe.step", 0, 10 * ms), ev("index.insert", 1 * ms, 2 * ms),
+        ev("index.insert", 2 * ms, 3 * ms)])])
+    read = load_reader("write.insert_ms_per_call")
+    red = tr.reduce_planes([dev, host], SPANS)
+    assert read(NS(trace=red, traced={"insert_calls": 2})) == \
+        pytest.approx(2.0)
+    assert read(NS(trace=red, traced={"insert_calls": 0})) is None
+    assert read(NS(trace=None, traced={"insert_calls": 2})) is None
+
+
+def test_batch_fill_open_reads_the_traced_counters():
+    from perfbench.harness import load_reader
+
+    read = load_reader("fe.batch_fill.open")
+    assert read(NS(fe_traced={"batches": 4, "dispatched_requests": 10})) \
+        == 2.5
+    assert read(NS(fe_traced={})) is None
